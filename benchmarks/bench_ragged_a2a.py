@@ -77,11 +77,11 @@ def _child(smoke: bool) -> None:
     from repro.core import dispatch as D
     from repro.core.moe import capacity, init_moe_params, moe_layer, \
         router_probs, topk_gates
-    from repro.sharding.compat import make_mesh, shard_map
     from repro.sharding.plan import plan_from_mesh
 
     P_ = 8
-    mesh = make_mesh((P_,), ("data",))
+    mesh = jax.make_mesh((P_,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     plan = plan_from_mesh(mesh)
     assert plan.ep == P_
     bpe = 4                                    # fp32 on the CPU emulation
@@ -106,9 +106,10 @@ def _child(smoke: bool) -> None:
                 y, st = moe_layer(p, xx, cfg, plan, act="gelu")
                 return y, st.drop_frac
 
-            fsm = jax.jit(shard_map(f, mesh=mesh,
-                                    in_specs=(pspecs, P("data", None)),
-                                    out_specs=(P("data", None), P())))
+            fsm = jax.jit(jax.shard_map(f, mesh=mesh,
+                                        in_specs=(pspecs, P("data", None)),
+                                        out_specs=(P("data", None), P()),
+                                        check_vma=False))
             timed_fn = lambda xx: fsm(params, xx)[0]
             drop = lambda xx: float(fsm(params, xx)[1])
             return timed_fn, params, drop
@@ -147,9 +148,10 @@ def _child(smoke: bool) -> None:
             return D.ragged_send_counts(starts, n_local_g)[None], \
                 jnp.int32(st.cap)[None]
 
-        cm = jax.jit(shard_map(counts_fn, mesh=mesh,
-                               in_specs=P("data", None),
-                               out_specs=(P("data"), P("data"))))
+        cm = jax.jit(jax.shard_map(counts_fn, mesh=mesh,
+                                   in_specs=P("data", None),
+                                   out_specs=(P("data"), P("data")),
+                                   check_vma=False))
         counts, blks = cm(x)
         counts = np.asarray(counts)                     # (P, P) [src, dst]
         block = int(np.asarray(blks)[0])
@@ -288,6 +290,9 @@ def _child(smoke: bool) -> None:
 
 def _spawn(extra) -> None:
     env = dict(os.environ)
+    # a CPU emulation of an 8-rank mesh: fake host devices, never a chip,
+    # so the child runs even where its parent already holds the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.pathsep.join(
         [ROOT, os.path.join(ROOT, "src")]

@@ -26,13 +26,12 @@ import dataclasses
 from typing import Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis import Finding
 
-# Primitive names (jax 0.4.x) of cross-device collectives.  pmean lowers to
-# psum; psum_scatter lowers to reduce_scatter; ragged_all_to_all is the
-# native ragged op of jax >= 0.4.38 (absent here, checked for the future).
+# Primitive names of cross-device collectives.  pmean lowers to psum;
+# psum_scatter lowers to reduce_scatter.
 COLLECTIVE_PRIMS = frozenset({
     "psum", "pmax", "pmin", "ppermute", "pshuffle", "all_to_all",
     "all_gather", "reduce_scatter", "psum_scatter", "ragged_all_to_all",
@@ -281,7 +280,6 @@ def _trace_moe(cfg, mesh, plan):
     from jax.sharding import PartitionSpec as P
 
     from repro.core.moe import init_moe_params, moe_layer
-    from repro.sharding.compat import shard_map
 
     d, t = 32, 64
     params = init_moe_params(jax.random.PRNGKey(0), cfg, d, plan)
@@ -296,9 +294,10 @@ def _trace_moe(cfg, mesh, plan):
         y, st = moe_layer(p, xx, cfg, plan, act="gelu")
         return y, st.lb_loss, st.drop_frac
 
-    fsm = shard_map(f, mesh=mesh,
-                    in_specs=(pspecs, P(("data", "model"), None)),
-                    out_specs=(P(("data", "model"), None), P(), P()))
+    fsm = jax.shard_map(f, mesh=mesh,
+                        in_specs=(pspecs, P(("data", "model"), None)),
+                        out_specs=(P(("data", "model"), None), P(), P()),
+                        check_vma=False)
     return jax.make_jaxpr(fsm)(params, x)
 
 
@@ -364,7 +363,6 @@ def _trace_serve(mesh, plan):
 
 def iter_entrypoints() -> Iterator[Tuple[str, jcore.ClosedJaxpr]]:
     """Trace the registered entrypoint grid on the 8-fake-device mesh."""
-    from repro.sharding.compat import make_mesh
     from repro.sharding.plan import test_plan
 
     if len(jax.devices()) < 8:
@@ -373,11 +371,12 @@ def iter_entrypoints() -> Iterator[Tuple[str, jcore.ClosedJaxpr]]:
             "run via `python -m repro.launch.analyze`, which forces "
             "XLA_FLAGS=--xla_force_host_platform_device_count=8 before "
             "importing jax")
-    mesh = make_mesh(MESH_SHAPE, MESH_AXES)
+    auto = (jax.sharding.AxisType.Auto,) * len(MESH_AXES)
+    mesh = jax.make_mesh(MESH_SHAPE, MESH_AXES, axis_types=auto)
     plan = test_plan(*MESH_SHAPE)
     for name, cfg in _moe_cases():
         yield name, _trace_moe(cfg, mesh, plan)
-    train_mesh = make_mesh((2, 2), MESH_AXES)
+    train_mesh = jax.make_mesh((2, 2), MESH_AXES, axis_types=auto)
     train_plan = test_plan(2, 2)
     for sentinel in (False, True):
         name = f"train_step/{'sentinel' if sentinel else 'plain'}"

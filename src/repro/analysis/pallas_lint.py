@@ -8,12 +8,18 @@ compiler params, which is everything the four rules need:
 * ``vmem-budget`` — per-grid-step VMEM footprint, estimated as 2x the sum
   of block bytes (Mosaic double-buffers every pipelined block) plus
   scratch bytes, against a configurable budget (default 16 MiB — one
-  TPUv4/v5 core's VMEM).
-* ``tile-alignment`` — the trailing block dim must be the full array dim,
-  a multiple of 128 (lanes), or 1; the second-to-last must be the full
-  dim, a multiple of the dtype's sublane count (fp32: 8, bf16: 16,
-  int8/fp8: 32), or 1.  Misaligned tiles compile to padded/strided Mosaic
-  windows that silently waste VMEM and VPU lanes.
+  TPUv4/v5 core's VMEM).  Each buffer's last dim is rounded up to 128
+  lanes and its second-to-last up to the dtype's sublane count, as Mosaic
+  tiles them; a second-to-last dim of 1 is tiled ``(1, 128)`` and stays 1.
+  Values the kernel body keeps live are not counted: a ``(n, 1, w)`` value
+  takes one sublane of each vreg, 8x its bytes, so reduce such rows one
+  at a time (the combine gather does).
+* ``tile-alignment`` — the trailing block dim must be the full array dim
+  or a multiple of 128 (lanes); the second-to-last must be the full dim or
+  a multiple of the dtype's sublane count (fp32: 8, bf16: 16, int8/fp8:
+  32).  A block dim of 1 is no exception: Mosaic refuses a ``(1, d)`` block
+  of a ``(T, d)`` array, though interpret mode runs it.  Lay such rows out
+  as ``(T, 1, d)``, where the block's last two dims are the array's own.
 * ``index-map-oob`` — index maps that depend only on grid indices are
   evaluated over the (corner-sampled) grid; a returned block index outside
   the padded operand bounds reads/writes out of bounds.  Maps that read
@@ -38,7 +44,7 @@ from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis import Finding
 from repro.analysis.jaxpr_lint import _sub_jaxprs
@@ -57,8 +63,8 @@ def _pallas_eqns(jaxpr: jcore.Jaxpr) -> Iterator[jcore.JaxprEqn]:
 
 
 def _src_of(eqn: jcore.JaxprEqn) -> Tuple[Optional[str], Optional[int]]:
-    """file:line of the kernel body from pallas_call's name_and_src_info."""
-    info = str(eqn.params.get("name_and_src_info", ""))
+    """file:line of the kernel body from its jaxpr's debug info."""
+    info = str(getattr(eqn.params["jaxpr"].debug_info, "func_src_info", ""))
     # format: "<kernel_name> at <file>:<line>"
     if " at " in info:
         loc = info.rsplit(" at ", 1)[1]
@@ -70,7 +76,30 @@ def _src_of(eqn: jcore.JaxprEqn) -> Tuple[Optional[str], Optional[int]]:
 
 
 def _block_dims(bm) -> Tuple[int, ...]:
-    return tuple(int(b) if isinstance(b, int) else 1 for b in bm.block_shape)
+    """Block extent per dim: ``Blocked(n)`` -> n, squeezed dims -> 1."""
+    return tuple(int(getattr(b, "block_size", 1) or 1)
+                 for b in bm.block_shape)
+
+
+def _tiled_bytes(dims: Sequence[int], dtype) -> int:
+    """Bytes of a VMEM buffer of shape ``dims`` once Mosaic tiles its last
+    two dims: lanes up to 128, sublanes up to the dtype's count (a unit
+    second-to-last dim keeps its ``(1, 128)`` tile)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    if not dims:
+        return itemsize
+    dims = list(dims)
+    dims[-1] = -(-dims[-1] // 128) * 128
+    if len(dims) >= 2 and dims[-2] > 1:
+        sub = _SUBLANE.get(itemsize, 8)
+        dims[-2] = -(-dims[-2] // sub) * sub
+    return math.prod(dims) * itemsize
+
+
+def _in_vmem(ref_aval) -> bool:
+    """Blocks with no explicit memory space are pipelined into VMEM."""
+    space = getattr(ref_aval, "memory_space", None)
+    return space is None or str(getattr(space, "value", space)) == "vmem"
 
 
 def _is_output(bm) -> bool:
@@ -98,8 +127,7 @@ def _eval_index_map(bm, point: Sequence[int], extra) -> Optional[Tuple[int, ...]
         except Exception:
             return None
     try:
-        out = jcore.eval_jaxpr(bm.index_map_jaxpr.jaxpr,
-                               bm.index_map_jaxpr.consts, *args)
+        out = jcore.jaxpr_as_fun(bm.index_map_jaxpr)(*args)
     except Exception:
         return None
     return tuple(int(x) for x in out)
@@ -118,7 +146,10 @@ def lint_pallas_call(eqn: jcore.JaxprEqn, *, name: str,
     findings: List[Finding] = []
     gm = eqn.params["grid_mapping"]
     grid = tuple(int(g) for g in gm.grid)
-    bms = list(gm.block_mappings)
+    # operands left in HBM (memory_space=pl.ANY) are not windowed into
+    # VMEM: the kernel DMAs from them itself, so no block rule applies
+    bms = [bm for bm in gm.block_mappings
+           if _in_vmem(bm.transformed_block_aval)]
     src_file, src_line = _src_of(eqn)
 
     def add(rule: str, msg: str):
@@ -128,14 +159,14 @@ def lint_pallas_call(eqn: jcore.JaxprEqn, *, name: str,
     # ---- VMEM footprint ----------------------------------------------------
     block_bytes = 0
     for bm in bms:
-        dims = _block_dims(bm)
-        block_bytes += math.prod(dims) * bm.array_shape_dtype.dtype.itemsize
+        block_bytes += _tiled_bytes(_block_dims(bm), bm.array_aval.dtype)
     body: jcore.Jaxpr = eqn.params["jaxpr"]
     n_scratch = gm.num_scratch_operands
     scratch_bytes = 0
     for v in (body.invars[len(body.invars) - n_scratch:] if n_scratch else ()):
         aval = v.aval
-        scratch_bytes += math.prod(aval.shape) * jnp.dtype(aval.dtype).itemsize
+        if _in_vmem(aval):            # not SMEM scalars or DMA semaphores
+            scratch_bytes += _tiled_bytes(aval.shape, aval.dtype)
     est = 2 * block_bytes + scratch_bytes
     if est > vmem_budget:
         add("vmem-budget",
@@ -147,20 +178,20 @@ def lint_pallas_call(eqn: jcore.JaxprEqn, *, name: str,
     # ---- tile alignment ----------------------------------------------------
     for bm in bms:
         dims = _block_dims(bm)
-        arr = bm.array_shape_dtype.shape
+        arr = bm.array_aval.shape
         if not dims:
             continue
-        itemsize = bm.array_shape_dtype.dtype.itemsize
+        itemsize = bm.array_aval.dtype.itemsize
         sub = _SUBLANE.get(itemsize, 8)
         b_last, a_last = dims[-1], arr[-1]
-        if not (b_last == a_last or b_last % 128 == 0 or b_last == 1):
+        if not (b_last == a_last or b_last % 128 == 0):
             add("tile-alignment",
                 f"{bm.origin}: trailing block dim {b_last} (array dim "
-                f"{a_last}) is neither the full dim, a multiple of 128 "
-                f"lanes, nor 1")
+                f"{a_last}) is neither the full dim nor a multiple of 128 "
+                f"lanes")
         if len(dims) >= 2:
             b2, a2 = dims[-2], arr[-2]
-            if not (b2 == a2 or b2 % sub == 0 or b2 == 1):
+            if not (b2 == a2 or b2 % sub == 0):
                 add("tile-alignment",
                     f"{bm.origin}: second-to-last block dim {b2} (array "
                     f"dim {a2}) is not a multiple of the {sub}-row "
@@ -171,7 +202,7 @@ def lint_pallas_call(eqn: jcore.JaxprEqn, *, name: str,
     revisited_axes: dict = {}
     for bm in bms:
         dims = _block_dims(bm)
-        arr = bm.array_shape_dtype.shape
+        arr = bm.array_aval.shape
         extra, uses_extra = _index_map_args(bm, len(grid))
         if uses_extra:
             continue            # data-dependent map: a runtime contract
@@ -208,7 +239,7 @@ def lint_pallas_call(eqn: jcore.JaxprEqn, *, name: str,
 
     # ---- dimension_semantics: presence + revisited axes sequential ---------
     cp = eqn.params.get("compiler_params") or {}
-    sem = (cp.get("mosaic") or {}).get("dimension_semantics")
+    sem = getattr(cp.get("mosaic_tpu"), "dimension_semantics", None)
     if sem is None:
         detail = ""
         if revisited_axes:

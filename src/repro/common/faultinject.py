@@ -139,7 +139,9 @@ def parse_fault_plan(spec: Optional[str]) -> Optional[FaultPlan]:
 
 
 def _rng(fp: FaultPlan, level: int, *shape_tag: int) -> random.Random:
-    return random.Random((fp.seed, fp.kind, level) + shape_tag)
+    # seeded by the tuple's text: Python 3.11+ seeds only from None, numbers,
+    # str and bytes, and a str seed is hashed the same in every process
+    return random.Random(repr((fp.seed, fp.kind, level) + shape_tag))
 
 
 # =============================================================================

@@ -81,7 +81,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
         # online-softmax state lives in kernel-local accumulators within one
         # grid step; no output or scratch crosses grid steps
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(qt, kt, vt)

@@ -138,7 +138,7 @@ def grouped_ffn_pallas(x: jax.Array, w1: jax.Array, w3, w2: jax.Array,
         out_specs=o_spec,
         # the output block accumulates over the f axis (innermost): that
         # axis is sequential; group and row-tile axes are independent
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
@@ -192,7 +192,7 @@ def grouped_ffn_ragged_pallas(rows: jax.Array, tile_gid: jax.Array,
         out_shape=jax.ShapeDtypeStruct((n_tiles, bt, d), rows.dtype),
         # each row tile's output accumulates over the f axis (innermost):
         # sequential; row tiles are independent
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tile_gid.astype(jnp.int32), *args)

@@ -73,15 +73,6 @@ ROUTER_FUSED_MIN_ROWS = 1024
 ROUTER_FUSED_MIN_EXPERTS = 3
 
 
-try:        # jax 0.4.x: public stop_gradient passes integer arrays through
-    from jax._src.ad_util import stop_gradient_p as _stop_gradient_p
-
-    def _stop_int_grads(x):
-        return _stop_gradient_p.bind(x)
-except ImportError:      # pragma: no cover - newer jax covers all dtypes
-    _stop_int_grads = jax.lax.stop_gradient
-
-
 def _router_fused_impl(x, w, k, renorm):
     if (x.shape[0] >= ROUTER_FUSED_MIN_ROWS
             and w.shape[1] >= ROUTER_FUSED_MIN_EXPERTS):
@@ -144,23 +135,39 @@ def router_fused(x, w, k, *, renorm: bool = False):
     # The integer outputs are routing decisions, not differentiable values.
     # Under remat, custom_vjp instantiates their tangents as concrete float0
     # arrays, which blow up in any downstream multiply (e.g. the combine
-    # path's group_ids * cap); jax.lax.stop_gradient is a no-op on integer
-    # dtypes, so bind the underlying primitive to restore symbolic-zero
-    # tangents — matching what the unfused chain's sort outputs carry.
-    return (gates, _stop_int_grads(idx), probs, logits,
-            _stop_int_grads(ranks), _stop_int_grads(starts))
+    # path's group_ids * cap); stop_gradient drops a tangent of any dtype,
+    # restoring the symbolic zeros the unfused chain's sort outputs carry.
+    stop = jax.lax.stop_gradient
+    return gates, stop(idx), probs, logits, stop(ranks), stop(starts)
+
+
+def _kernel_fwd_oracle_bwd(kernel, oracle):
+    """``kernel`` with the VJP of its ``ref`` twin as its backward pass.
+
+    Pallas calls have no transpose rule, and those with scalar-prefetched
+    operands have no JVP rule either, so ``jax.grad`` cannot pass through
+    them.  The twin computes the same function in jnp, so its VJP is the
+    gradient of the kernel's forward; integer operands get float0
+    cotangents from ``jax.vjp`` as usual.
+    """
+    f = jax.custom_vjp(kernel)
+    f.defvjp(lambda *args: (kernel(*args), args),
+             lambda args, ct: jax.vjp(oracle, *args)[1](ct))
+    return f
 
 
 def grouped_ffn(x, w1, w3, w2, *, act: str = "gelu"):
     """Grouped expert FFN; falls back to the jnp oracle for tiny shapes
-    (interpret-mode overhead dominates below one MXU tile)."""
+    (interpret-mode overhead dominates below one MXU tile).  Differentiable:
+    the backward pass is the oracle's VJP."""
     G, T, d = x.shape
     if T < 16 or d % 8:
         return ref.grouped_ffn_ref(x, w1, w3, w2, act=act)
-    return grouped_ffn_pallas(x, w1.astype(x.dtype),
-                              None if w3 is None else w3.astype(x.dtype),
-                              w2.astype(x.dtype), act=act,
-                              interpret=_interpret())
+    f = _kernel_fwd_oracle_bwd(
+        partial(grouped_ffn_pallas, act=act, interpret=_interpret()),
+        partial(ref.grouped_ffn_ref, act=act))
+    return f(x, w1.astype(x.dtype),
+             None if w3 is None else w3.astype(x.dtype), w2.astype(x.dtype))
 
 
 def grouped_ffn_ragged(rows, group_starts, w1, w3, w2, *, block: int,
@@ -191,8 +198,10 @@ def dispatch_gather(x, src):
         return jnp.zeros((R, d), x.dtype)
     if R < 16 or d % 8:
         return ref.dispatch_gather_ref(x, src)
-    return dispatch_gather_pallas(x, src.astype(jnp.int32),
-                                  interpret=_interpret())
+    f = _kernel_fwd_oracle_bwd(
+        partial(dispatch_gather_pallas, interpret=_interpret()),
+        ref.dispatch_gather_ref)
+    return f(x, src.astype(jnp.int32))
 
 
 def combine_gather(rows, src, scale):
@@ -204,9 +213,10 @@ def combine_gather(rows, src, scale):
         return jnp.zeros((t, d), rows.dtype)
     if t < 16 or d % 8:
         return ref.combine_gather_ref(rows, src, scale)
-    return combine_gather_pallas(rows, src.astype(jnp.int32),
-                                 scale.astype(jnp.float32),
-                                 interpret=_interpret())
+    f = _kernel_fwd_oracle_bwd(
+        partial(combine_gather_pallas, interpret=_interpret()),
+        ref.combine_gather_ref)
+    return f(rows, src.astype(jnp.int32), scale.astype(jnp.float32))
 
 
 def flash_attention(q, k, v):

@@ -68,11 +68,11 @@ BLOCK_ROWS = 128
 
 def _group_sort_kernel(keys_ref, local_ref, hist_ref, count_ref, *,
                        n_tiles: int):
-    """One grid step = one (1, bt) tile of keys.
+    """One grid step = one (1, 1, bt) tile of keys.
 
     ``count_ref``: (1, D) int32 VMEM scratch — running per-key histogram of
     every tile BEFORE this one (persists across the sequential grid).
-    ``local_ref``: (1, bt) int32 — this tile's per-element count of earlier
+    ``local_ref``: (1, 1, bt) int32 — this tile's per-element count of earlier
     equal keys over the whole array.  ``hist_ref``: (1, D) int32 — final
     histogram, written once on the last step.
     """
@@ -82,9 +82,9 @@ def _group_sort_kernel(keys_ref, local_ref, hist_ref, count_ref, *,
     def _init():
         count_ref[...] = jnp.zeros_like(count_ref)
 
-    bt = local_ref.shape[1]
+    bt = local_ref.shape[2]
     D = count_ref.shape[1]
-    kt = keys_ref[...]                                        # (1, bt) int32
+    kt = keys_ref[0]                                          # (1, bt) int32
     keys = kt.reshape(bt, 1)
     dom = jax.lax.broadcasted_iota(jnp.int32, (bt, D), 1)
     onehot = (keys == dom).astype(jnp.int32)                  # (bt, D)
@@ -103,7 +103,7 @@ def _group_sort_kernel(keys_ref, local_ref, hist_ref, count_ref, *,
     # int32: the running count reaches A, and an fp32 pick would silently
     # round once A exceeds 2^24.
     run_pick = (count_ref[...] * onehot).sum(axis=1)          # (bt,) int32
-    local_ref[...] = (within + run_pick).reshape(1, bt)
+    local_ref[0] = (within + run_pick).reshape(1, bt)
 
     count_ref[...] = count_ref[...] + onehot.sum(axis=0, keepdims=True)
 
@@ -136,7 +136,10 @@ def group_sort_pallas(keys: jax.Array, num_keys: int, *,
                 jnp.zeros((num_keys + 1,), jnp.int32))
     # the tile is never shrunk below ``block``: Mosaic wants lane-aligned
     # block shapes, so a short input pads up to one full tile of sentinels
-    # rather than compiling a ragged (1, A) block
+    # rather than compiling a ragged block.  Tiles are laid out
+    # (n_tiles, 1, bt): a block's last two dims are then the array's own
+    # (1, bt), which Mosaic accepts, where a (1, bt) block of an
+    # (n_tiles, bt) array breaks the 8-row sublane tiling
     bt = block
     pad = (-A) % bt
     k32 = keys.astype(jnp.int32)
@@ -148,18 +151,18 @@ def group_sort_pallas(keys: jax.Array, num_keys: int, *,
     local, hist = pl.pallas_call(
         functools.partial(_group_sort_kernel, n_tiles=n_tiles),
         grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((1, bt), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, bt), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec((1, 1, bt), lambda i: (i, 0, 0))],
+        out_specs=[pl.BlockSpec((1, 1, bt), lambda i: (i, 0, 0)),
                    pl.BlockSpec((1, D), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n_tiles, bt), jnp.int32),
+        out_shape=[jax.ShapeDtypeStruct((n_tiles, 1, bt), jnp.int32),
                    jax.ShapeDtypeStruct((1, D), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((1, D), jnp.int32)],
         # the running histogram (scratch + revisited hist output) is
         # carried across the tile axis: it must execute sequentially
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(kp.reshape(n_tiles, bt))
+    )(kp.reshape(n_tiles, 1, bt))
     # pad-sentinel counts live at hist[num_keys] and are excluded by
     # construction: starts only prefixes the real domain
     starts = jnp.concatenate([

@@ -191,7 +191,7 @@ def router_fused_pallas(x: jax.Array, w: jax.Array, k: int, *,
         scratch_shapes=[pltpu.VMEM((1, D), jnp.int32)],
         # the running histogram (scratch + revisited hist output) is
         # carried across the tile axis: it must execute sequentially
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(xp, w)

@@ -7,35 +7,59 @@ per-head state matrix ``S (hd, hd)`` resident in VMEM/VREGs and streams the
 O(T^2). Grid ``(B, nh)``: heads and batches are independent, so the kernel
 parallelizes across them (heads are also the tensor-parallel shard dim).
 
-For hd=64 the state is 16 KB fp32; r/k/v/w tiles for a 4k sequence are
+Layout.  Each step needs ``r, k, w`` as columns ``(hd, 1)`` and ``v`` as a
+row ``(1, hd)``, and Mosaic reads a VMEM ref only at whole 8-row tiles.  So
+the wrapper lays ``r, k, w`` out time-along-lanes ``(B, nh, hd, T)`` and
+``v``/``y`` time-along-rows ``(B, nh, T, hd)``, with T padded to whole
+``CHUNK``s (``k = v = r = 0``, ``w = 1``: the padded steps leave the state
+unchanged).  The kernel loads one tile-aligned ``CHUNK`` of time at a time
+and picks step ``i`` out of it with an exact masked reduction.
+
+For hd=64 the state is 16 KB fp32; the r/k/v/w tiles of a 4k sequence are
 4 x 1 MB — comfortably VMEM-resident.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+CHUNK = 128     # time steps per aligned load: one lane width
+
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, s_out_ref):
-    T, hd = r_ref.shape[1], r_ref.shape[3]
-    u = u_ref[0].astype(jnp.float32)                 # (hd,)
-    s0 = s0_ref[0, 0].astype(jnp.float32)            # (hd, hd)
+    hd, Tp = r_ref.shape[2], r_ref.shape[3]
+    u = u_ref[0].astype(jnp.float32)                 # (hd, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (hd, CHUNK), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, hd), 0)
 
-    def body(t, s):
-        r = r_ref[0, t, 0].astype(jnp.float32)        # (hd,)
-        k = k_ref[0, t, 0].astype(jnp.float32)
-        v = v_ref[0, t, 0].astype(jnp.float32)
-        w = w_ref[0, t, 0].astype(jnp.float32)
-        kv = k[:, None] * v[None, :]                  # (hd_k, hd_v)
-        y = ((s + u[:, None] * kv) * r[:, None]).sum(axis=0)
-        y_ref[0, t, 0] = y.astype(y_ref.dtype)
-        return w[:, None] * s + kv
+    def chunk(c, s):
+        t0 = pl.multiple_of(c * CHUNK, CHUNK)
+        R, K, W = (ref[0, 0, :, pl.ds(t0, CHUNK)].astype(jnp.float32)
+                   for ref in (r_ref, k_ref, w_ref))          # (hd, C)
+        V = v_ref[0, 0, pl.ds(t0, CHUNK), :].astype(jnp.float32)  # (C, hd)
 
-    s_last = jax.lax.fori_loop(0, T, body, s0)
+        def step(i, carry):
+            s, Y = carry
+
+            def col(M):                               # step i of (hd, C)
+                return jnp.sum(jnp.where(lane == i, M, 0.0), axis=1,
+                               keepdims=True)
+            r, k, w = col(R), col(K), col(W)          # (hd, 1)
+            v = jnp.sum(jnp.where(row == i, V, 0.0), axis=0,
+                        keepdims=True)                # (1, hd)
+            kv = k * v                                # (hd_k, hd_v)
+            y = jnp.sum((s + u * kv) * r, axis=0, keepdims=True)
+            return w * s + kv, jnp.where(row == i, y, Y)
+
+        s, Y = jax.lax.fori_loop(0, CHUNK, step,
+                                 (s, jnp.zeros((CHUNK, hd), jnp.float32)))
+        y_ref[0, 0, pl.ds(t0, CHUNK), :] = Y.astype(y_ref.dtype)
+        return s
+
+    s_last = jax.lax.fori_loop(0, Tp // CHUNK, chunk,
+                               s0_ref[0, 0].astype(jnp.float32))
     s_out_ref[0, 0] = s_last.astype(s_out_ref.dtype)
 
 
@@ -47,22 +71,33 @@ def rwkv6_scan_pallas(r: jax.Array, k: jax.Array, v: jax.Array,
     Returns (y (B, T, nh, hd), s_last (B, nh, hd, hd)).
     """
     B, T, nh, hd = r.shape
-    grid = (B, nh)
-    seq_spec = pl.BlockSpec((1, T, 1, hd), lambda b, h: (b, 0, h, 0))
+    pad = (-T) % CHUNK
+    Tp = T + pad
+
+    def padded(a, fill):
+        return jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)),
+                       constant_values=fill) if pad else a
+
+    # (B, nh, hd, Tp): time along lanes; v stays time along rows
+    rT, kT, wT = (padded(a, fill).transpose(0, 2, 3, 1)
+                  for a, fill in ((r, 0), (k, 0), (w, 1)))
+    vh = padded(v, 0).transpose(0, 2, 1, 3)
+    col_spec = pl.BlockSpec((1, 1, hd, Tp), lambda b, h: (b, h, 0, 0))
+    row_spec = pl.BlockSpec((1, 1, Tp, hd), lambda b, h: (b, h, 0, 0))
+    state_spec = pl.BlockSpec((1, 1, hd, hd), lambda b, h: (b, h, 0, 0))
     y, s_last = pl.pallas_call(
         _kernel,
-        out_shape=(jax.ShapeDtypeStruct((B, T, nh, hd), jnp.float32),
+        out_shape=(jax.ShapeDtypeStruct((B, nh, Tp, hd), jnp.float32),
                    jax.ShapeDtypeStruct((B, nh, hd, hd), jnp.float32)),
-        grid=grid,
-        in_specs=[seq_spec, seq_spec, seq_spec, seq_spec,
-                  pl.BlockSpec((1, hd), lambda b, h: (h, 0)),
-                  pl.BlockSpec((1, 1, hd, hd), lambda b, h: (b, h, 0, 0))],
-        out_specs=(seq_spec,
-                   pl.BlockSpec((1, 1, hd, hd), lambda b, h: (b, h, 0, 0))),
+        grid=(B, nh),
+        in_specs=[col_spec, col_spec, row_spec, col_spec,
+                  pl.BlockSpec((1, hd, 1), lambda b, h: (h, 0, 0)),
+                  state_spec],
+        out_specs=(row_spec, state_spec),
         # the time recurrence runs inside one grid step (fori over T);
         # (batch, head) grid steps are independent
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(r, k, v, w, u, s0)
-    return y, s_last
+    )(rT, kT, vh, wT, u.reshape(nh, hd, 1), s0)
+    return y.transpose(0, 2, 1, 3)[:, :T], s_last

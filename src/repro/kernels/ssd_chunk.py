@@ -14,12 +14,17 @@ materializing the (Q, Q) decay matrix only in VMEM (the jnp reference builds
 a (B, nc, Q, Q, nh) tensor in HBM). The sequential inter-chunk recurrence
 (tiny: (hd, ds) state per head) stays in ``lax.scan`` outside the kernel.
 
-Working set at Q=128, hd=64, ds=64: Q*hd + 2*Q*ds + Q*Q + hd*ds fp32
-~ 160 KB — far under VMEM; both matmul shapes are 128-aligned.
+The O(Q) terms — ``cs``, ``exp(cs_Q - cs) * dt`` and ``a_chunk`` — are
+computed by XLA in the wrapper, which also lays every per-head operand out
+head-major with the chunk's Q steps in the last two dims: Mosaic accepts a
+block only when its last two dims are tile multiples or the array's own,
+and ``cs``/``dt`` arrive in both a row ``(1, Q)`` and a column ``(Q, 1)``
+form, so the kernel needs no transpose or cumulative sum.
+
+Working set at Q=128, hd=64, ds=64: 2*Q*hd + 2*Q*ds + Q*Q + hd*ds fp32
+~ 200 KB — far under VMEM; both matmul shapes are 128-aligned.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -27,29 +32,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, dt_ref, loga_ref, b_ref, c_ref,
-            y_ref, sb_ref, ac_ref):
-    x = x_ref[0, 0, :, 0, :].astype(jnp.float32)        # (Q, hd)
-    dt = dt_ref[0, 0, :, 0].astype(jnp.float32)         # (Q,)
-    loga = loga_ref[0, 0, :, 0].astype(jnp.float32)     # (Q,)
-    B = b_ref[0, 0].astype(jnp.float32)                 # (Q, ds)
-    C = c_ref[0, 0].astype(jnp.float32)                 # (Q, ds)
+def _kernel(x_ref, xt_ref, csr_ref, csc_ref, dtr_ref, tdt_ref, b_ref, c_ref,
+            y_ref, sb_ref):
+    x = x_ref[0, 0].astype(jnp.float32)                 # (Q, hd)
+    xT = xt_ref[0, 0].astype(jnp.float32)               # (hd, Q)
+    cs_r = csr_ref[0, 0]                                # (1, Q)
+    cs_c = csc_ref[0, 0]                                # (Q, 1)
+    dt_r = dtr_ref[0, 0]                                # (1, Q)
+    tail_dt = tdt_ref[0, 0]                             # (1, Q)
+    B = b_ref[0].astype(jnp.float32)                    # (Q, ds)
+    C = c_ref[0].astype(jnp.float32)                    # (Q, ds)
     Q = x.shape[0]
 
-    cs = jnp.cumsum(loga)                               # (Q,)
-    scores = jnp.dot(C, B.T, preferred_element_type=jnp.float32)  # (Q,Q)
-    decay = cs[:, None] - cs[None, :]
-    mask = jax.lax.iota(jnp.int32, Q)[:, None] >= \
-        jax.lax.iota(jnp.int32, Q)[None, :]
+    scores = jax.lax.dot_general(                       # C B^T  (Q, Q)
+        C, B, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    decay = cs_c - cs_r
+    mask = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) >= \
+        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     w = jnp.where(mask, jnp.exp(decay), 0.0) * scores   # (Q, Q)
-    y = jnp.dot(w * dt[None, :], x,
-                preferred_element_type=jnp.float32)     # (Q, hd)
-    tail = jnp.exp(cs[-1] - cs)                         # (Q,)
-    sb = jnp.dot((tail * dt)[:, None].T * x.T, B,
-                 preferred_element_type=jnp.float32)    # (hd, ds)
-    y_ref[0, 0, :, 0, :] = y
-    sb_ref[0, 0, 0] = sb
-    ac_ref[0, 0, 0] = jnp.exp(cs[-1])
+    y_ref[0, 0] = jnp.dot(w * dt_r, x,
+                          preferred_element_type=jnp.float32)   # (Q, hd)
+    sb_ref[0, 0] = jnp.dot(xT * tail_dt, B,
+                           preferred_element_type=jnp.float32)  # (hd, ds)
 
 
 def ssd_chunk_pallas(xh, dt, loga, Bc, Cc, *, interpret: bool = False):
@@ -60,36 +64,36 @@ def ssd_chunk_pallas(xh, dt, loga, Bc, Cc, *, interpret: bool = False):
     """
     B, nc, Q, nh, hd = xh.shape
     ds = Bc.shape[-1]
-    grid = (B * nc, nh)
-    xr = xh.reshape(B * nc, 1, Q, nh, hd)
-    dtr = dt.reshape(B * nc, 1, Q, nh)
-    lr = loga.reshape(B * nc, 1, Q, nh)
-    br = Bc.reshape(B * nc, 1, Q, ds)
-    cr = Cc.reshape(B * nc, 1, Q, ds)
+    G = B * nc
+    f32 = jnp.float32
+    x = xh.reshape(G, Q, nh, hd).transpose(0, 2, 1, 3)      # (G, nh, Q, hd)
+    xT = x.transpose(0, 1, 3, 2)                            # (G, nh, hd, Q)
+    cs = jnp.cumsum(loga.astype(f32), axis=2).reshape(G, Q, nh)
+    dtf = dt.astype(f32).reshape(G, Q, nh)
+    tail_dt = jnp.exp(cs[:, -1:, :] - cs) * dtf
 
-    y, sb, ac = pl.pallas_call(
+    def row(a):                                             # (G, nh, 1, Q)
+        return a.transpose(0, 2, 1)[:, :, None, :]
+
+    per_head = lambda *blk: pl.BlockSpec(blk, lambda g, h: (g, h, 0, 0))
+    shared = pl.BlockSpec((1, Q, ds), lambda g, h: (g, 0, 0))
+    y, sb = pl.pallas_call(
         _kernel,
-        out_shape=(jax.ShapeDtypeStruct((B * nc, 1, Q, nh, hd), jnp.float32),
-                   jax.ShapeDtypeStruct((B * nc, 1, nh, hd, ds), jnp.float32),
-                   jax.ShapeDtypeStruct((B * nc, 1, nh), jnp.float32)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, Q, 1, hd), lambda g, h: (g, 0, 0, h, 0)),
-            pl.BlockSpec((1, 1, Q, 1), lambda g, h: (g, 0, 0, h)),
-            pl.BlockSpec((1, 1, Q, 1), lambda g, h: (g, 0, 0, h)),
-            pl.BlockSpec((1, 1, Q, ds), lambda g, h: (g, 0, 0, 0)),
-            pl.BlockSpec((1, 1, Q, ds), lambda g, h: (g, 0, 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, 1, Q, 1, hd), lambda g, h: (g, 0, 0, h, 0)),
-            pl.BlockSpec((1, 1, 1, hd, ds), lambda g, h: (g, 0, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1), lambda g, h: (g, 0, h)),
-        ),
-        # intra-chunk recurrence runs inside one grid step; the cross-chunk
-        # stitch happens in the outer associative scan, not in this kernel
-        compiler_params=pltpu.TPUCompilerParams(
+        out_shape=(jax.ShapeDtypeStruct((G, nh, Q, hd), f32),
+                   jax.ShapeDtypeStruct((G, nh, hd, ds), f32)),
+        grid=(G, nh),
+        in_specs=[per_head(1, 1, Q, hd), per_head(1, 1, hd, Q),
+                  per_head(1, 1, 1, Q), per_head(1, 1, Q, 1),
+                  per_head(1, 1, 1, Q), per_head(1, 1, 1, Q),
+                  shared, shared],
+        out_specs=(per_head(1, 1, Q, hd), per_head(1, 1, hd, ds)),
+        # every (chunk, head) cell is independent; the cross-chunk stitch
+        # happens in the outer scan, not in this kernel
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(xr, dtr, lr, br, cr)
-    return (y.reshape(B, nc, Q, nh, hd), sb.reshape(B, nc, nh, hd, ds),
-            ac.reshape(B, nc, nh))
+    )(x, xT, row(cs), row(cs).transpose(0, 1, 3, 2), row(dtf), row(tail_dt),
+      Bc.reshape(G, Q, ds), Cc.reshape(G, Q, ds))
+    return (y.transpose(0, 2, 1, 3).reshape(B, nc, Q, nh, hd),
+            sb.reshape(B, nc, nh, hd, ds),
+            jnp.exp(cs[:, -1, :]).reshape(B, nc, nh))

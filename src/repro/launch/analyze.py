@@ -6,15 +6,17 @@ provenance, and exits nonzero if anything was flagged.  Wired into
 ``./ci.sh --static``.
 
 The jaxpr pass traces the entrypoint grid through ``shard_map`` on a
-(4, 2) mesh, so this module forces 8 fake CPU devices via ``XLA_FLAGS``
-*before* jax is imported — run it as a subprocess (as ci.sh and the tests
-do), not inside a process that already initialized jax.
+(4, 2) mesh, so this module pins the CPU platform and forces 8 fake CPU
+devices via ``XLA_FLAGS`` *before* jax is imported — run it as a
+subprocess (as ci.sh and the tests do), not inside a process that already
+initialized jax.  It only traces, so it never needs (or takes) a chip.
 """
 from __future__ import annotations
 
 import os
 import sys
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 _FLAG = "--xla_force_host_platform_device_count=8"
 if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()

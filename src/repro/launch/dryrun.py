@@ -1,4 +1,7 @@
 import os
+# a CPU-only tool: 512 fake host devices stand in for the production mesh,
+# and pinning the platform keeps it off any chip the machine has
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 DOC = """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh).
@@ -225,6 +228,8 @@ def main():
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if not args.all:
         smile = None if args.router is None else (args.router == "smile")

@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.sharding.compat import make_mesh
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -16,12 +16,15 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     Multi-pod: 2 pods x 256 chips; the ``pod`` axis crosses the DCN."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2,
                    pod: int = 0) -> jax.sharding.Mesh:
-    """Small fake-device mesh for CPU multi-device tests."""
+    """Small mesh over the visible devices: fake host devices in CPU
+    multi-device tests, or the chips of one host (``(2, 2)`` on a v5e 2x2)."""
     if pod:
-        return make_mesh((pod, n_data, n_model), ("pod", "data", "model"))
-    return make_mesh((n_data, n_model), ("data", "model"))
+        return jax.make_mesh((pod, n_data, n_model), ("pod", "data", "model"),
+                             axis_types=(_AUTO,) * 3)
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(_AUTO,) * 2)

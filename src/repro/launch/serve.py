@@ -125,6 +125,8 @@ def main():
                     help="engine mode: synthetic ragged requests to submit")
     add_option_flags(ap, SERVE_OPTIONS)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.engine:
         serve_engine(args.arch, reduced=args.reduced, requests=args.requests,
                      prompt_len=args.prompt_len, new_tokens=args.new_tokens,

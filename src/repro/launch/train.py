@@ -1,8 +1,9 @@
 """Training driver.
 
-Runs real optimization steps on whatever devices exist (one CPU here; the
-production mesh on TPU — the same code path, only the mesh changes). For
-CPU-scale runs pass a reduced arch (``--reduced``).
+Runs real optimization steps on whatever devices exist: one device with no
+mesh, or every chip of a mesh (``train(..., mesh=...)``) — the same code
+path, only the mesh changes.  For CPU-scale runs pass a reduced arch
+(``--reduced``).
 
   PYTHONPATH=src python -m repro.launch.train --arch smile-3.7b --reduced \
       --steps 50 --batch 16 --seq 128
@@ -17,14 +18,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common.config import MOE_OPTIONS, TRAIN_OPTIONS, TrainConfig
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
+
+from repro.common.config import (MOE_OPTIONS, TRAIN_OPTIONS, ModelConfig,
+                                 TrainConfig)
 from repro.configs import get_config, get_reduced
 from repro.data.pipeline import DataPipeline
 from repro.models.transformer import init_model
 from repro.optim import make_optimizer, make_schedule
 from repro.sharding.plan import plan_from_mesh, single_device_plan
+from repro.sharding.specs import param_specs
 from repro.train.checkpoint import CheckpointManager, save_checkpoint
-from repro.train.step import build_train_step
+from repro.train.step import build_train_step, opt_state_specs, zero1_state
 
 _UNSET = object()       # float-flag default (argparse type-converts string
                         # defaults, so "" cannot be the sentinel there)
@@ -90,7 +96,7 @@ def parse_moe_option_flags(args) -> dict:
     return parse_option_flags(args, MOE_OPTIONS)
 
 
-def train(arch: str, *, reduced: bool = True, steps: int = 50,
+def train(arch: str | ModelConfig, *, reduced: bool = True, steps: int = 50,
           batch: int = 16, seq: int = 128, lr: float = 3e-4,
           optimizer: str = "lamb", seed: int = 0, log_every: int = 10,
           ckpt: str = "", mesh=None, micro_batch: int = 0,
@@ -102,6 +108,10 @@ def train(arch: str, *, reduced: bool = True, steps: int = 50,
           halt_after: int = 0):
     """Run (or resume) a training run.
 
+    ``arch`` is a registry name (``reduced`` picks its 2-layer variant) or
+    a :class:`ModelConfig`, trained as given.  With a ``mesh``, parameters
+    and optimizer state are created already sharded by the step's specs.
+
     Robust-runtime knobs: ``sentinel`` turns on the in-jit step sentinel
     (bad steps skipped, anomaly counters carried + checkpointed);
     ``ckpt_dir`` + ``ckpt_every`` keep a ``ckpt_keep``-deep checksummed
@@ -112,7 +122,10 @@ def train(arch: str, *, reduced: bool = True, steps: int = 50,
     keeping the FULL ``steps`` schedule horizon — the crash-simulation
     hook the resume-determinism test uses.
     """
-    cfg = get_reduced(arch) if reduced else get_config(arch)
+    if isinstance(arch, ModelConfig):
+        cfg = arch
+    else:
+        cfg = get_reduced(arch) if reduced else get_config(arch)
     # moe_options is the registry-validated path; the three string kwargs
     # are the legacy surface, folded in for backward compatibility
     opts = dict(moe_options or {})
@@ -133,14 +146,28 @@ def train(arch: str, *, reduced: bool = True, steps: int = 50,
                        ckpt_keep=ckpt_keep, ckpt_dir=ckpt_dir)
 
     key = jax.random.PRNGKey(seed)
-    params = init_model(key, cfg, plan)
     opt = make_optimizer(optimizer)
     sched = make_schedule("cosine", lr, tcfg.warmup_steps, steps)
-    if zero1:
-        from repro.train.step import zero1_state
-        opt_state = zero1_state(params, cfg, plan)
+
+    def init_state():
+        params = init_model(key, cfg, plan)
+        if zero1:
+            return params, zero1_state(params, cfg, plan)
+        return params, opt.init(params)
+
+    shardings = None
+    if mesh is None:
+        params, opt_state = init_state()
     else:
-        opt_state = opt.init(params)
+        # initialised in place, by the step's own specs: no leaf is ever
+        # whole on one chip (the partitionable threefry draws the same
+        # numbers however the arrays are sharded)
+        pspec = param_specs(jax.eval_shape(init_state)[0], cfg, plan)
+        shardings = jax.tree.map(
+            lambda s: NamedSharding(mesh, s),
+            (pspec, opt_state_specs(pspec, plan, zero1=zero1)),
+            is_leaf=lambda x: isinstance(x, P))
+        params, opt_state = jax.jit(init_state, out_shardings=shardings)()
     sent = None
     if sentinel:
         from repro.train.sentinel import init_sentinel_state
@@ -158,6 +185,9 @@ def train(arch: str, *, reduced: bool = True, steps: int = 50,
                 params, opt_state, start, sent = got
             else:
                 params, opt_state, start = got
+            if shardings is not None:
+                params, opt_state = jax.device_put((params, opt_state),
+                                                  shardings)
             print(f"resumed from step {start} ({mgr.dir})")
         else:
             print(f"no valid checkpoint in {mgr.dir} — starting fresh")
@@ -247,6 +277,8 @@ def main():
     add_moe_option_flags(ap)
     add_option_flags(ap, TRAIN_OPTIONS)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     train(args.arch, reduced=args.reduced, steps=args.steps, batch=args.batch,
           seq=args.seq, lr=args.lr, optimizer=args.optimizer, seed=args.seed,
           ckpt=args.ckpt, micro_batch=args.micro_batch,

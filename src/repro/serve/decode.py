@@ -18,7 +18,6 @@ from repro.common.config import ModelConfig
 from repro.models import transformer as T
 from repro.models.layers import gather_full_logits
 from repro.sharding import comm
-from repro.sharding.compat import shard_map
 from repro.sharding.plan import MeshPlan
 from repro.sharding.specs import batch_specs, cache_specs, param_specs
 
@@ -73,9 +72,9 @@ def build_decode_step(cfg: ModelConfig, plan: MeshPlan, params_like,
     pspec = param_specs(params_like, cfg, plan)
     cspec = cache_specs(caches_like, cfg, plan, batch)
     tspec = batch_specs({"t": token_like}, plan)["t"]
-    sm = shard_map(fn, mesh=mesh,
-                   in_specs=(pspec, tspec, cspec, P()),
-                   out_specs=(tspec, cspec))
+    sm = jax.shard_map(fn, mesh=mesh,
+                       in_specs=(pspec, tspec, cspec, P()),
+                       out_specs=(tspec, cspec), check_vma=False)
     return jax.jit(sm, donate_argnums=(2,))
 
 
@@ -90,7 +89,7 @@ def build_prefill(cfg: ModelConfig, plan: MeshPlan, params_like,
     tok_spec = batch_specs({"t": tokens_like}, plan)["t"]
     out_tok = P(tok_spec[0]) if cfg.num_codebooks <= 1 else \
         P(tok_spec[0], None)
-    sm = shard_map(fn, mesh=mesh,
-                   in_specs=(pspec, tok_spec, cspec),
-                   out_specs=(out_tok, cspec))
+    sm = jax.shard_map(fn, mesh=mesh,
+                       in_specs=(pspec, tok_spec, cspec),
+                       out_specs=(out_tok, cspec), check_vma=False)
     return jax.jit(sm, donate_argnums=(2,))

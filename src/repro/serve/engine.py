@@ -40,7 +40,6 @@ from repro.core.pipeline import zero_stats
 from repro.models import transformer as T
 from repro.serve import kvcache as KV
 from repro.serve.decode import greedy_sample
-from repro.sharding.compat import shard_map
 from repro.sharding.plan import MeshPlan
 from repro.sharding.specs import cache_specs, param_specs
 
@@ -111,10 +110,11 @@ def build_paged_decode_step(cfg: ModelConfig, plan: MeshPlan, params_like,
     tp = plan.tp_axis
     lspec = P(None, tuple(tp) if isinstance(tp, (list, tuple)) and len(tp) > 1
               else (tp[0] if isinstance(tp, (list, tuple)) and tp else tp))
-    sm = shard_map(fn, mesh=mesh,
-                   in_specs=(pspec, P(None), cspec, P(None, None), P(None),
+    sm = jax.shard_map(fn, mesh=mesh,
+                       in_specs=(pspec, P(None), cspec, P(None, None), P(None),
                              P(None)),
-                   out_specs=(P(None), lspec, _stats_specs(), cspec))
+                       out_specs=(P(None), lspec, _stats_specs(), cspec),
+                       check_vma=False)
     return jax.jit(sm, donate_argnums=(2,))
 
 
@@ -125,10 +125,10 @@ def build_paged_prefill(cfg: ModelConfig, plan: MeshPlan, params_like,
         return jax.jit(fn, donate_argnums=(2,))
     pspec = param_specs(params_like, cfg, plan)
     cspec = cache_specs(caches_like, cfg, plan, 1)
-    sm = shard_map(fn, mesh=mesh,
-                   in_specs=(pspec, P(None, None), cspec, P(None, None),
+    sm = jax.shard_map(fn, mesh=mesh,
+                       in_specs=(pspec, P(None, None), cspec, P(None, None),
                              P(), P()),
-                   out_specs=(P(), _stats_specs(), cspec))
+                       out_specs=(P(), _stats_specs(), cspec), check_vma=False)
     return jax.jit(sm, donate_argnums=(2,))
 
 

@@ -263,6 +263,16 @@ def exchange_counts(send_counts: jax.Array, axes: Axes) -> jax.Array:
                           concat_axis=0).reshape(P)
 
 
+def _native_ragged_a2a() -> bool:
+    """Whether the enclosing ``shard_map``'s mesh is made of accelerator
+    devices, where ``lax.ragged_all_to_all`` lowers; XLA:CPU has no
+    lowering for it, so CPU meshes (the tests' fake host devices) take the
+    fused-slab emulation.  Read from the mesh being traced, so an AOT
+    compile against a described TPU topology takes the native op too."""
+    dev = jax.sharding.get_abstract_mesh().abstract_device
+    return dev is not None and dev.device_kind != "cpu"
+
+
 def ragged_all_to_all(rows: jax.Array, send_counts: jax.Array, axes: Axes,
                       *, recv_rows: int, seg_rows: Optional[int] = None,
                       recv_counts: Optional[jax.Array] = None,
@@ -293,9 +303,10 @@ def ragged_all_to_all(rows: jax.Array, send_counts: jax.Array, axes: Axes,
 
     Three wire strategies behind the same contract, picked by ``emulation``:
 
-    * ``"auto"`` + ``lax.ragged_all_to_all`` available (jax >= 0.4.38) —
-      the native op; exact segment bytes move.
-    * ``"auto"``/``"a2a"`` otherwise — the P rotation rounds fused into ONE
+    * ``"auto"`` on a mesh of accelerator devices — the native
+      ``lax.ragged_all_to_all``; exact segment bytes move.
+    * ``"auto"`` on a CPU mesh (XLA:CPU cannot run the native op), or
+      ``"a2a"`` — the P rotation rounds fused into ONE
       ``lax.all_to_all`` of the ``(P, R)`` staging slab (entry ``p`` is the
       buffer rolled so peer ``p``'s segment starts at row 0), followed by a
       single count-driven compaction gather.  Ships ``P * R`` rows but as
@@ -328,10 +339,9 @@ def ragged_all_to_all(rows: jax.Array, send_counts: jax.Array, axes: Axes,
 
     The ``REPRO_RAGGED_A2A_EMULATION`` environment variable overrides an
     ``"auto"`` selection (values: ``auto``/``a2a``/``ppermute``) — the
-    recoverable escape hatch if a future jax's native op misbehaves (it is
-    auto-selected the moment the installed jax provides it, which no CI
-    here can exercise): forcing an oracle-verified emulation keeps the wire
-    semantics instead of falling all the way back to padded capacity hops.
+    recoverable escape hatch if the native op misbehaves on a chip:
+    forcing an oracle-verified emulation keeps the wire semantics instead
+    of falling all the way back to padded capacity hops.
     """
     import os
     if emulation == "auto":
@@ -348,7 +358,7 @@ def ragged_all_to_all(rows: jax.Array, send_counts: jax.Array, axes: Axes,
         out = out.at[:n].set(rows[:n])
         return out, send_counts
     send_off = excl_cumsum(send_counts)
-    if emulation == "auto" and hasattr(lax, "ragged_all_to_all"):
+    if emulation == "auto" and _native_ragged_a2a():
         # native path: my segment for peer p lands on p at the offset where
         # p expects MY slice — sum over sources before me of what they send
         # to p, i.e. row ``me`` of the source-exclusive cumsum of the full

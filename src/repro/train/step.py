@@ -27,7 +27,6 @@ from repro.optim.zero1 import (Zero1State, init_state_shapes, state_specs,
                                zero1_apply, zero1_reduce_and_clip)
 from repro.train import sentinel as SEN
 from repro.sharding import comm
-from repro.sharding.compat import shard_map
 from repro.sharding.plan import MeshPlan
 from repro.sharding.specs import (batch_specs, param_specs, shard_axes,
                                   sharded_axes_only)
@@ -212,10 +211,7 @@ def build_train_step(cfg: ModelConfig, tcfg: TrainConfig, plan: MeshPlan,
     if mesh is None:
         return jax.jit(fn, donate_argnums=(0, 1)), pspec
 
-    if zero1:
-        ospec = state_specs(pspec, sync_tree, norm_tree)
-    else:
-        ospec = {"m": pspec, "v": pspec, "step": P()}
+    ospec = opt_state_specs(pspec, plan, zero1=zero1)
     bspec = batch_specs(batch_like, plan)
     mkeys = ["ce", "lb", "z", "mtp", "drop_frac", "loss", "grad_norm", "lr",
              "fault_events", "wire_faults", "max_load", "load_entropy"]
@@ -225,14 +221,24 @@ def build_train_step(cfg: ModelConfig, tcfg: TrainConfig, plan: MeshPlan,
     if sentinel:
         from repro.train.sentinel import init_sentinel_state
         sspec = jax.tree.map(lambda _: P(), init_sentinel_state())
-        sm = shard_map(fn, mesh=mesh,
-                       in_specs=(pspec, ospec, bspec, P(), sspec),
-                       out_specs=(pspec, ospec, mspec, sspec))
+        sm = jax.shard_map(fn, mesh=mesh,
+                           in_specs=(pspec, ospec, bspec, P(), sspec),
+                           out_specs=(pspec, ospec, mspec, sspec),
+                           check_vma=False)
     else:
-        sm = shard_map(fn, mesh=mesh,
-                       in_specs=(pspec, ospec, bspec, P()),
-                       out_specs=(pspec, ospec, mspec))
+        sm = jax.shard_map(fn, mesh=mesh,
+                           in_specs=(pspec, ospec, bspec, P()),
+                           out_specs=(pspec, ospec, mspec), check_vma=False)
     return jax.jit(sm, donate_argnums=(0, 1)), pspec
+
+
+def opt_state_specs(pspec, plan: MeshPlan, *, zero1: bool = False):
+    """Partition specs of the optimizer state the step takes and returns,
+    for parameters with specs ``pspec``."""
+    if zero1:
+        return state_specs(pspec, shard_axes(pspec, plan),
+                           sharded_axes_only(pspec, plan))
+    return {"m": pspec, "v": pspec, "step": P()}
 
 
 def zero1_state(params_like, cfg: ModelConfig, plan: MeshPlan):

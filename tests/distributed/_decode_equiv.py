@@ -17,10 +17,10 @@ from repro.configs import get_reduced
 from repro.data.pipeline import synthetic_tokens
 from repro.models.transformer import init_caches, init_model
 from repro.serve.decode import build_decode_step, build_prefill
-from repro.sharding.compat import make_mesh, shard_map
 from repro.sharding.plan import single_device_plan, test_plan
 
-mesh = make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 plan = test_plan(n_inter=2, n_intra=2)
 oracle = single_device_plan()
 B, PROMPT, NEW = 4, 16, 6
@@ -69,9 +69,10 @@ for noisy in ["zamba2-2.7b", "deepseek-v3-671b"]:
         _, lg, _, _ = forward(p, t, cfg, plan, positions=jnp.arange(PROMPT))
         return lg
 
-    fsm = jax.jit(shard_map(f, mesh=mesh,
-                            in_specs=(pspec, P("data", None)),
-                            out_specs=P("data", None, "model")))
+    fsm = jax.jit(jax.shard_map(f, mesh=mesh,
+                                in_specs=(pspec, P("data", None)),
+                                out_specs=P("data", None, "model"),
+                                check_vma=False))
     dist_lg = fsm(params, toks)
     a, b = np.asarray(ref_lg, np.float32), np.asarray(dist_lg, np.float32)
     rel = np.abs(a - b).max() / np.abs(a).max()
@@ -108,10 +109,10 @@ def moe_tick(p, x, valid):
     y, _ = moe_layer(p, x, moe_cfg, plan, token_valid=valid)
     return y
 
-tick = jax.jit(shard_map(
+tick = jax.jit(jax.shard_map(
     moe_tick, mesh=mesh,
     in_specs=(mspecs, P(("data", "model"), None), P(("data", "model"))),
-    out_specs=P(("data", "model"), None)))
+    out_specs=P(("data", "model"), None), check_vma=False))
 y_dist = tick(mp_params, xx, live)
 a, b = np.asarray(y_ref, np.float32), np.asarray(y_dist, np.float32)
 dead = ~np.asarray(live)

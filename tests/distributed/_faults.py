@@ -57,10 +57,10 @@ from jax.sharding import PartitionSpec as P
 from repro.common import faultinject as FI
 from repro.common.config import MoEConfig
 from repro.core.moe import init_moe_params, moe_layer
-from repro.sharding.compat import make_mesh, shard_map
 from repro.sharding.plan import test_plan
 
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 plan = test_plan(n_inter=4, n_intra=2)
 NDEV = 8
 d = 32
@@ -93,9 +93,9 @@ def run_dist(cfg, params, x):
         return (y, st.drop_frac, st.hop_drop_frac, st.fault_events,
                 st.hop_max_load, st.hop_load_entropy, st.wire_faults)
 
-    fsm = jax.jit(shard_map(
+    fsm = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(pspecs, P(("data", "model"), None)),
-        out_specs=(P(("data", "model"), None),) + (P(),) * 6))
+        out_specs=(P(("data", "model"), None),) + (P(),) * 6, check_vma=False))
     return fsm(params, x)
 
 

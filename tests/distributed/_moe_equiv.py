@@ -29,10 +29,10 @@ from jax.sharding import PartitionSpec as P
 
 from repro.common.config import MoEConfig
 from repro.core.moe import init_moe_params, moe_layer
-from repro.sharding.compat import make_mesh, shard_map
 from repro.sharding.plan import single_device_plan, test_plan
 
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 plan = test_plan(n_inter=4, n_intra=2)
 oracle = single_device_plan()
 d = 32
@@ -62,9 +62,9 @@ def run_dist(cfg, params, x):
         y, st = moe_layer(params, x, cfg, plan, act="gelu")
         return y, st.lb_loss, st.drop_frac
 
-    fsm = jax.jit(shard_map(
+    fsm = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(pspecs, P(("data", "model"), None)),
-        out_specs=(P(("data", "model"), None), P(), P())))
+        out_specs=(P(("data", "model"), None), P(), P()), check_vma=False))
     return fsm(params, x)
 
 

@@ -34,9 +34,9 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding import comm
-from repro.sharding.compat import make_mesh, shard_map
 
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 R, d = 24, 5
 rng = np.random.default_rng(0)
 
@@ -64,9 +64,10 @@ def run_exchange(rows, counts, axes, p, emulation="auto"):
                                          emulation=emulation)
         return out[None], rc[None]
 
-    fsm = jax.jit(shard_map(
+    fsm = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P(("data", "model")), P(("data", "model"))),
-        out_specs=(P(("data", "model")), P(("data", "model")))))
+        out_specs=(P(("data", "model")), P(("data", "model"))),
+        check_vma=False))
     return fsm(jnp.asarray(rows), jnp.asarray(counts))
 
 
@@ -99,9 +100,10 @@ def check_joint(counts, label, emulation="auto"):
                                               emulation=emulation)
         return back[None], back_c[None]
 
-    fsm = jax.jit(shard_map(
+    fsm = jax.jit(jax.shard_map(
         rev, mesh=mesh, in_specs=(P(("data", "model")), P(("data", "model"))),
-        out_specs=(P(("data", "model")), P(("data", "model")))))
+        out_specs=(P(("data", "model")), P(("data", "model"))),
+        check_vma=False))
     back, back_c = fsm(jnp.asarray(rows), jnp.asarray(counts))
     np.testing.assert_array_equal(np.asarray(back_c), counts, err_msg=label)
     masked = rows.copy()
@@ -164,9 +166,10 @@ def check_truncated(counts, bound, label, emulation):
                                          allow_truncate=True)
         return out[None], rc[None]
 
-    fsm = jax.jit(shard_map(
+    fsm = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P(("data", "model")), P(("data", "model"))),
-        out_specs=(P(("data", "model")), P(("data", "model")))))
+        out_specs=(P(("data", "model")), P(("data", "model"))),
+        check_vma=False))
     got, _ = fsm(jnp.asarray(rows), jnp.asarray(counts))
     want, kept = trunc_oracle(rows, counts, bound)
     np.testing.assert_array_equal(np.asarray(got), want, err_msg=label)

@@ -14,17 +14,18 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.common.config import TrainConfig
 from repro.configs import get_reduced
 from repro.data.pipeline import make_batch
 from repro.models.transformer import init_model
 from repro.optim import make_optimizer, make_schedule
-from repro.sharding.compat import make_mesh, shard_map
 from repro.sharding.plan import single_device_plan, test_plan
 from repro.train.step import build_train_step
 
-mesh = make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 plan = test_plan(n_inter=2, n_intra=2)
 oracle = single_device_plan()
 
@@ -68,4 +69,28 @@ for name in ARCHS:
     assert dl < 2e-2, (name, dl)
     assert rel_g < 6e-2, (name, rel_g)
     assert maxerr < 5e-3, (name, maxerr)
+# the launcher's own loop: with a mesh, train() creates parameters and
+# optimizer state already sharded by the step's specs, and its run matches
+# the same run on one device
+from repro.launch.train import train  # noqa: E402
+from repro.sharding.plan import plan_from_mesh  # noqa: E402
+from repro.sharding.specs import param_specs  # noqa: E402
+
+cfg = get_reduced("smile-3.7b")
+kw = dict(steps=2, batch=8, seq=32, log_every=1)
+p_one, h_one = train(cfg, **kw)
+p_mesh, h_mesh = train(cfg, mesh=mesh, **kw)
+for leaf, spec in zip(jax.tree.leaves(p_mesh),
+                      jax.tree.leaves(param_specs(p_mesh, cfg,
+                                                  plan_from_mesh(mesh)),
+                                      is_leaf=lambda x: isinstance(x, P))):
+    want = jax.sharding.NamedSharding(mesh, spec)
+    assert leaf.sharding.is_equivalent_to(want, leaf.ndim), (leaf.sharding,
+                                                             spec)
+dl = abs(h_one[0]["loss"] - h_mesh[0]["loss"])
+maxerr = max(jax.tree.leaves(jax.tree.map(
+    lambda a, b: float(jnp.max(jnp.abs(a - b))), p_one,
+    jax.device_get(p_mesh))))
+print(f"{'train() on mesh':20s} dloss={dl:.2e} dparam={maxerr:.2e}")
+assert dl < 2e-2 and maxerr < 5e-3, (dl, maxerr)
 print("ALL TRAIN EQUIV OK")

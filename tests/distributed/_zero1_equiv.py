@@ -14,11 +14,11 @@ from repro.configs import get_reduced
 from repro.data.pipeline import make_batch
 from repro.models.transformer import init_model
 from repro.optim import make_optimizer, make_schedule
-from repro.sharding.compat import make_mesh, shard_map
 from repro.sharding.plan import single_device_plan, test_plan
 from repro.train.step import build_train_step, zero1_state
 
-mesh = make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 plan = test_plan(2, 2)
 oracle = single_device_plan()
 

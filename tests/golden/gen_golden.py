@@ -12,7 +12,15 @@ Bit-level float reproducibility only holds within one (platform, jax
 version) pair — both are recorded in the fixture and the test falls back to
 tight allclose when they differ from the running environment.
 
-    PYTHONPATH=src python tests/golden/gen_golden.py
+The layer parameters are stored in the fixture too (``p|<router>|<leaf>``),
+so the test never redraws them: ``PRNGKey(0)`` draws different numbers
+under ``jax_threefry_partitionable=True`` (jax's default since 0.5), and the
+recorded outputs were produced under the old ``False`` setting, which
+:func:`golden_params` pins.
+
+    PYTHONPATH=src python tests/golden/gen_golden.py            # everything
+    PYTHONPATH=src python tests/golden/gen_golden.py --params   # add params,
+                                                   # keep recorded outputs
 """
 import os
 import sys
@@ -48,16 +56,46 @@ def layer_cfg(router, backend, ragged, sort_impl, capacity_factor):
                      sort_impl=sort_impl)
 
 
+def golden_params():
+    """{router: layer params} drawn from ``PRNGKey(0)`` exactly as the
+    recorded outputs were: with the non-partitionable threefry."""
+    params = {}
+    with jax.threefry_partitionable(False):
+        for router in ("switch", "smile"):
+            cfg0 = layer_cfg(router, "dense", True, "argsort", 8.0)
+            params[router] = M.init_moe_params(jax.random.PRNGKey(0), cfg0,
+                                               32, PLAN, glu=False)
+    return params
+
+
+def param_arrays(params) -> dict:
+    """Flatten {router: params} to the fixture's ``p|router|leaf`` keys."""
+    out = {}
+    for router, tree in params.items():
+        for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+            name = "/".join(str(k.key) for k in path)
+            out[f"p|{router}|{name}"] = np.asarray(leaf)
+    return out
+
+
+def add_params(path):
+    """Store the parameters in an existing fixture, leaving its recorded
+    inputs, outputs and environment as they are."""
+    with np.load(path, allow_pickle=False) as old:
+        out = {k: old[k] for k in old.files if not k.startswith("p|")}
+    out.update(param_arrays(golden_params()))
+    np.savez_compressed(path, **out)
+    print(f"wrote {path} (parameters added to the recorded fixture)")
+
+
 def main(out_path):
-    key = jax.random.PRNGKey(0)
-    x = jax.random.normal(jax.random.PRNGKey(1), (48, 32))
+    with jax.threefry_partitionable(False):
+        x = jax.random.normal(jax.random.PRNGKey(1), (48, 32))
     out = {"x": np.asarray(x)}
     meta = {"jax_version": jax.__version__,
             "platform": jax.default_backend()}
-    params = {}
-    for router in ("switch", "smile"):
-        cfg0 = layer_cfg(router, "dense", True, "argsort", 8.0)
-        params[router] = M.init_moe_params(key, cfg0, 32, PLAN, glu=False)
+    params = golden_params()
+    out.update(param_arrays(params))
     for router in ("switch", "smile"):
         for case, kw in CASES.items():
             for backend in BACKENDS:
@@ -78,8 +116,12 @@ def main(out_path):
 
 
 if __name__ == "__main__":
-    # optional argv[1]: write elsewhere (e.g. to diff a regeneration against
-    # the checked-in fixture without clobbering it)
-    main(sys.argv[1] if len(sys.argv) > 1 else
-         os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "moe_layer_golden.npz"))
+    # optional path argument: write elsewhere (e.g. to diff a regeneration
+    # against the checked-in fixture without clobbering it)
+    args = [a for a in sys.argv[1:] if a != "--params"]
+    target = args[0] if args else os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "moe_layer_golden.npz")
+    if "--params" in sys.argv[1:]:
+        add_params(target)
+    else:
+        main(target)

@@ -89,7 +89,7 @@ def test_oversized_vmem_block_flagged():
             in_specs=[pl.BlockSpec((1, 2048, 2048), lambda i: (i, 0, 0))],
             out_specs=pl.BlockSpec((1, 2048, 2048), lambda i: (i, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((2, 2048, 2048), jnp.float32),
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=True)(x)
 
@@ -101,6 +101,30 @@ def test_oversized_vmem_block_flagged():
                                         vmem_budget=1 << 30) == []
 
 
+def test_vmem_budget_counts_lane_padding():
+    """An 8-wide f32 block fills 8 of 128 lanes: 512 KiB of data, double
+    buffered in and out, occupies 8 MiB of VMEM once tiled."""
+    def f(x):
+        def k(x_ref, o_ref):
+            o_ref[...] = x_ref[...]
+        return pl.pallas_call(
+            k, grid=(2,),
+            in_specs=[pl.BlockSpec((1, 4096, 8), lambda i: (i, 0, 0))],
+            out_specs=pl.BlockSpec((1, 4096, 8), lambda i: (i, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((2, 4096, 8), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)),
+            interpret=True)(x)
+
+    eqn = _trace_pallas(f, jnp.zeros((2, 4096, 8), jnp.float32))
+    got = pallas_lint.lint_pallas_call(eqn, name="fixture",
+                                       vmem_budget=4 << 20)
+    assert [g.rule for g in got] == ["vmem-budget"]
+    assert "8.0 MiB" in got[0].message
+    assert pallas_lint.lint_pallas_call(eqn, name="fixture",
+                                        vmem_budget=9 << 20) == []
+
+
 def test_scratch_across_parallel_axis_flagged():
     """Accumulating output revisited across an axis marked parallel."""
     def f(x):
@@ -108,14 +132,14 @@ def test_scratch_across_parallel_axis_flagged():
             o_ref[...] = o_ref[...] + x_ref[...]
         return pl.pallas_call(
             k, grid=(4,),
-            in_specs=[pl.BlockSpec((1, 128), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((1, 128), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((1, 128), jnp.float32),
-            compiler_params=pltpu.TPUCompilerParams(
+            in_specs=[pl.BlockSpec((8, 128), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=True)(x)
 
-    eqn = _trace_pallas(f, jnp.zeros((4, 128), jnp.float32))
+    eqn = _trace_pallas(f, jnp.zeros((32, 128), jnp.float32))
     got = pallas_lint.lint_pallas_call(eqn, name="fixture")
     assert [g.rule for g in got] == ["grid-race"]
     assert "axis 0" in got[0].message
@@ -128,14 +152,40 @@ def test_missing_semantics_and_oob_flagged():
         return pl.pallas_call(
             k, grid=(4,),
             # off-by-one: block i+1 walks past the final block of x
-            in_specs=[pl.BlockSpec((1, 128), lambda i: (i + 1, 0))],
-            out_specs=pl.BlockSpec((1, 128), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((4, 128), jnp.float32),
+            in_specs=[pl.BlockSpec((8, 128), lambda i: (i + 1, 0))],
+            out_specs=pl.BlockSpec((8, 128), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((32, 128), jnp.float32),
             interpret=True)(x)
 
-    eqn = _trace_pallas(f, jnp.zeros((4, 128), jnp.float32))
+    eqn = _trace_pallas(f, jnp.zeros((32, 128), jnp.float32))
     rules = {g.rule for g in pallas_lint.lint_pallas_call(eqn, name="fixture")}
     assert rules == {"index-map-oob", "missing-dimension-semantics"}
+
+
+@pytest.mark.parametrize("rows,width", [(256, 768), (8, 128)])
+def test_single_row_block_flagged(rows, width):
+    """A (1, d) block of a (T, d) array (a row gather) or a (1, bt) block of
+    an (n, bt) array (a key tile): interpret mode runs both, Mosaic refuses
+    them.  The same rows laid out (T, 1, d) are clean."""
+    def copy(block, index, shape):
+        def f(x):
+            def k(x_ref, o_ref):
+                o_ref[...] = x_ref[...]
+            spec = pl.BlockSpec(block, index)
+            return pl.pallas_call(
+                k, grid=(rows,), in_specs=[spec], out_specs=spec,
+                out_shape=jax.ShapeDtypeStruct(shape, jnp.float32),
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("parallel",)),
+                interpret=True)(x)
+        return _trace_pallas(f, jnp.zeros(shape, jnp.float32))
+
+    eqn = copy((1, width), lambda i: (i, 0), (rows, width))
+    got = pallas_lint.lint_pallas_call(eqn, name="fixture")
+    assert [g.rule for g in got] == ["tile-alignment"] * 2
+    assert "second-to-last block dim 1" in got[0].message
+    eqn = copy((1, 1, width), lambda i: (i, 0, 0), (rows, 1, width))
+    assert pallas_lint.lint_pallas_call(eqn, name="fixture") == []
 
 
 # ----------------------------------------------------------------- repo pass

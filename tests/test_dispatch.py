@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.common.config import MoEConfig
 from repro.core import dispatch as D

@@ -13,14 +13,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import ops as kops
 from repro.kernels import ref
 from repro.kernels.radix_sort import group_sort_pallas
 
-# named adversarial key distributions, indexed by a drawn integer so the
-# offline hypothesis fallback (integers/floats only) can select them too
+# named adversarial key distributions, indexed by a drawn integer
 _DISTRIBUTIONS = ("uniform", "one_group", "two_ends", "sorted", "reversed",
                   "skewed")
 

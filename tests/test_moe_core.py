@@ -1,14 +1,14 @@
 """MoE core invariants: routing, capacity, dispatch/combine, LB losses.
 
-Includes property tests on the dispatch machinery (hypothesis when
-available, deterministic replay otherwise — see _hypothesis_compat) and the
+Includes hypothesis property tests on the dispatch machinery and the
 paper's Eq. 4 minimum (loss_lb -> alpha + beta at uniform routing).
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.common.config import MoEConfig
 from repro.core import moe as M

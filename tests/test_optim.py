@@ -5,7 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.optim import make_optimizer, make_schedule
 from repro.optim.optimizers import clip_by_global_norm

@@ -67,11 +67,18 @@ def golden_env(golden):
 
 @pytest.fixture(scope="module")
 def golden_params(golden):
-    key = jax.random.PRNGKey(0)
+    """The recorded layer parameters (``p|<router>|<leaf path>``): drawn
+    once by ``gen_golden.py``, never redrawn here, since the PRNG's draws
+    depend on the jax version's threefry setting."""
     params = {}
-    for router in ("switch", "smile"):
-        cfg = _layer_cfg(router, "dense", True, "argsort", 8.0)
-        params[router] = M.init_moe_params(key, cfg, 32, PLAN, glu=False)
+    for name in golden.files:
+        if name.startswith("p|"):
+            _, router, path = name.split("|")
+            node = params.setdefault(router, {})
+            *parents, leaf = path.split("/")
+            for p in parents:
+                node = node.setdefault(p, {})
+            node[leaf] = jnp.asarray(golden[name])
     return params, jnp.asarray(golden["x"])
 
 

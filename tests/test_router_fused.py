@@ -28,15 +28,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import moe as M
 from repro.kernels import ops as kops
 from repro.kernels import ref
 from repro.kernels.router_fused import router_fused_pallas
 
-# named adversarial input families, indexed by a drawn integer so the
-# offline hypothesis fallback (integers/floats only) can select them too
+# named adversarial input families, indexed by a drawn integer
 _DISTRIBUTIONS = ("normal", "bf16", "dup_experts", "all_tied", "bf16_dup")
 
 
