@@ -1,0 +1,49 @@
+#!/usr/bin/env python3
+"""Run one benchmark cell once and print its result line.
+
+    python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+The cell is ``bench/workloads/<cell>.json``.  The run needs the chips the
+cell asks for; with no accelerator, or too few chips, it exits nonzero and
+prints no result.  The last line of standard output is one JSON object:
+``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end
+metrics, or with ``--trace 1`` its per-layer metrics), ``device``, with
+``--trace 1`` a ``breakdown``, and last ``checks``: each number the
+correctness check compared, with its limit.
+"""
+import time
+
+T_START = time.perf_counter()
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import sys  # noqa: E402
+from pathlib import Path  # noqa: E402
+
+ROOT = Path(__file__).resolve().parent.parent
+sys.path[:0] = [str(ROOT), str(ROOT / "src")]
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args()
+    from bench import harness
+    harness.enable_compile_cache()
+    try:
+        out = harness.run_cell(args.workload, args.seed, args.seconds,
+                               bool(args.trace), t_start=T_START)
+    except harness.NoAccelerator as e:
+        print(f"[bench] {e}", file=sys.stderr)
+        return 2
+    for k, v in out["checks"].items():
+        print(f"check {k} {v['value']!r} limit {v['limit']!r}", file=sys.stderr)
+    print(json.dumps(out), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
