@@ -877,60 +877,76 @@ def execute_pipeline(x: jax.Array, hops: Sequence[ExpertHop],
 
     def run_hop(level: int, x: jax.Array, token_valid: jax.Array,
                 outer_gid: Optional[jax.Array]) -> jax.Array:
+        # each hop's device ops are named ``hop<level>/<phase>``; hop 1 runs
+        # inside hop 0, between its two exchanges
+        with jax.named_scope(f"hop{level}"):
+            return hop_body(level, x, token_valid, outer_gid)
+
+    def hop_body(level, x, token_valid, outer_gid):
         hop = hops[level]
         spec = hop.spec
         innermost = level == len(hops) - 1
-        dec = hop.route(x, token_valid, outer_gid)
-        if fp is not None and fp.kind == "skew" and fp.targets(level):
-            dec = FI.apply_skew(fp, level, dec, spec.num_groups,
-                                spec.loss_groups)
-        A, k = dec.group_ids.shape[0], dec.k
-        gid = (dec.group_ids if spec.perm is None
-               else jnp.take(spec.perm, dec.group_ids))
         nanrows_here = (fp is not None and fp.kind == "nanrows"
                         and fp.targets(level))
+        with jax.named_scope("route"):
+            dec = hop.route(x, token_valid, outer_gid)
+            if fp is not None and fp.kind == "skew" and fp.targets(level):
+                dec = FI.apply_skew(fp, level, dec, spec.num_groups,
+                                    spec.loss_groups)
+            A, k = dec.group_ids.shape[0], dec.k
+            gid = (dec.group_ids if spec.perm is None
+                   else jnp.take(spec.perm, dec.group_ids))
 
-        # ---- losses (one path per hop) --------------------------------------
-        f, p = lb_loss_terms(dec.probs, dec.top1, dec.token_valid,
-                             spec.loss_groups, sync)
-        lb_terms.append(scaled_lb_loss(f, p, spec.lb_coef))
-        z_terms.append(z_loss(dec.logits, dec.token_valid,
-                              cfg.router_z_coef, sync))
-        # router-collapse watchdog inputs, from the already-global f-vector:
-        # max-load fraction and normalized load entropy (1 = uniform)
-        hop_maxload[level] = jnp.max(f)
-        if spec.loss_groups > 1:
-            fr = f / jnp.maximum(f.sum(), 1e-9)
-            ent = -jnp.sum(fr * jnp.log(jnp.maximum(fr, 1e-20)))
-            hop_entropy[level] = ent / math.log(spec.loss_groups)
+            # ---- losses (one path per hop) ----------------------------------
+            f, p = lb_loss_terms(dec.probs, dec.top1, dec.token_valid,
+                                 spec.loss_groups, sync)
+            lb_terms.append(scaled_lb_loss(f, p, spec.lb_coef))
+            z_terms.append(z_loss(dec.logits, dec.token_valid,
+                                  cfg.router_z_coef, sync))
+            # router-collapse watchdog inputs, from the already-global
+            # f-vector: max-load fraction and normalized load entropy
+            # (1 = uniform)
+            hop_maxload[level] = jnp.max(f)
+            if spec.loss_groups > 1:
+                fr = f / jnp.maximum(f.sum(), 1e-9)
+                ent = -jnp.sum(fr * jnp.log(jnp.maximum(fr, 1e-20)))
+                hop_entropy[level] = ent / math.log(spec.loss_groups)
 
         # ---- dispatch + exchange + inner compute + reverse + combine --------
         if spec.exchange == "local":
             # capacity-free and exchange-free: the expert grid backing this
             # hop is local — FFN straight over exact ragged segment lengths
-            rows, starts, st = D.dispatch_ragged(
-                x, gid, dec.gates, spec.num_groups, k=k, valid=dec.valid,
-                use_kernel=use_kernel, sort_impl=simpl)
+            with jax.named_scope("dispatch"):
+                rows, starts, st = D.dispatch_ragged(
+                    x, gid, dec.gates, spec.num_groups, k=k, valid=dec.valid,
+                    use_kernel=use_kernel, sort_impl=simpl)
             if nanrows_here:
                 rows = FI.nan_rows(fp, level, rows, _occupancy(st, A) > 0)
-            out = experts_ffn_ragged(wsel, rows, starts, act, block=st.cap,
-                                     use_kernel=use_kernel)
-            return D.combine(out, st)               # nothing CAN drop: 0.0
+            with jax.named_scope("expert_ffn"):
+                out = experts_ffn_ragged(wsel, rows, starts, act,
+                                         block=st.cap, use_kernel=use_kernel)
+            with jax.named_scope("combine"):
+                return D.combine(out, st)           # nothing CAN drop: 0.0
 
         if spec.exchange == "ragged":
-            rows, starts, st = D.dispatch_ragged(
-                x, gid, dec.gates, spec.num_groups, k=k, valid=dec.valid,
-                use_kernel=use_kernel, sort_impl=simpl)
-            seg_lens = D.ragged_seg_lens(gid, st.keep, spec.num_groups)
-            hs, ev, wbad = _ragged_forward(rows, starts, seg_lens, spec,
-                                           st.cap, fp=fp, level=level)
+            with jax.named_scope("dispatch"):
+                rows, starts, st = D.dispatch_ragged(
+                    x, gid, dec.gates, spec.num_groups, k=k, valid=dec.valid,
+                    use_kernel=use_kernel, sort_impl=simpl)
+                seg_lens = D.ragged_seg_lens(gid, st.keep, spec.num_groups)
+            with jax.named_scope("exchange"):
+                hs, ev, wbad = _ragged_forward(rows, starts, seg_lens, spec,
+                                               st.cap, fp=fp, level=level)
             if innermost:
-                y_slab = experts_ffn_compact_rows(
-                    wsel, hs.recv, hs.gid, hs.valid, spec.groups_per_rank,
-                    act, use_kernel, sort_impl=simpl)
+                with jax.named_scope("expert_ffn"):
+                    y_slab = experts_ffn_compact_rows(
+                        wsel, hs.recv, hs.gid, hs.valid,
+                        spec.groups_per_rank, act, use_kernel,
+                        sort_impl=simpl)
             else:
                 y_slab = run_hop(level + 1, hs.recv, hs.valid, hs.gid)
-            back, survived, rbad = _ragged_reverse(y_slab, hs, spec)
+            with jax.named_scope("exchange"):
+                back, survived, rbad = _ragged_reverse(y_slab, hs, spec)
             # wire verdicts: every flagged source is one fault event and one
             # per-src-rank localization count (forward + reverse directions)
             for verdict in (wbad, rbad):
@@ -944,45 +960,56 @@ def execute_pipeline(x: jax.Array, hops: Sequence[ExpertHop],
             hop_faults[level] = ev
             if survived is None:
                 # capacity-free end-to-end: exact-constant 0.0, no psum
-                return D.combine(back, st)
+                with jax.named_scope("combine"):
+                    return D.combine(back, st)
             keep = st.keep & jnp.take(survived, jnp.maximum(st.pos, 0))
             dropped = comm.psum((st.keep & ~keep).sum().astype(jnp.float32),
                                 sync)
             total = comm.psum(st.keep.sum().astype(jnp.float32), sync)
             hop_drops[level] = dropped / jnp.maximum(total, 1)
-            return D.combine(back, dataclasses.replace(st, keep=keep))
+            with jax.named_scope("combine"):
+                return D.combine(back, dataclasses.replace(st, keep=keep))
 
         # ---- padded: fixed-shape capacity buffer on the wire ----------------
         hop_backend = "sort" if dropless else cfg.dispatch_backend
-        buf, st = D.dispatch(x, gid, dec.gates, spec.num_groups,
-                             spec.capacity, k=k, valid=dec.valid,
-                             backend=hop_backend, use_kernel=use_kernel,
-                             sort_impl=simpl)
-        recv = _fold(buf, spec)                     # (gpr, P*cap, d)
+        with jax.named_scope("dispatch"):
+            buf, st = D.dispatch(x, gid, dec.gates, spec.num_groups,
+                                 spec.capacity, k=k, valid=dec.valid,
+                                 backend=hop_backend, use_kernel=use_kernel,
+                                 sort_impl=simpl)
+        with jax.named_scope("exchange"):
+            recv = _fold(buf, spec)                 # (gpr, P*cap, d)
         if nanrows_here:
-            occ = _fold(_occupancy(st, A), spec) > 0
+            with jax.named_scope("exchange"):
+                occ = _fold(_occupancy(st, A), spec) > 0
             recv = FI.nan_rows(fp, level, recv.reshape(-1, recv.shape[-1]),
                                occ.reshape(-1)).reshape(recv.shape)
         if innermost:
             if dropless:
                 # fixed-shape A2A retained; FFN only sees valid rows
-                rvalid = _fold(_occupancy(st, A), spec) > 0
-                out = experts_ffn_compact(wsel, recv, rvalid, act,
-                                          use_kernel, sort_impl=simpl)
+                with jax.named_scope("exchange"):
+                    rvalid = _fold(_occupancy(st, A), spec) > 0
+                with jax.named_scope("expert_ffn"):
+                    out = experts_ffn_compact(wsel, recv, rvalid, act,
+                                              use_kernel, sort_impl=simpl)
             else:
-                out = experts_ffn(wsel, recv, act, use_kernel)
+                with jax.named_scope("expert_ffn"):
+                    out = experts_ffn(wsel, recv, act, use_kernel)
         else:
             gpr, S, d = recv.shape
             x1 = recv.reshape(gpr * S, d)
-            valid1 = _fold(_occupancy(st, A), spec).reshape(gpr * S) > 0
+            with jax.named_scope("exchange"):
+                valid1 = _fold(_occupancy(st, A), spec).reshape(gpr * S) > 0
             gid1 = jnp.repeat(jnp.arange(gpr, dtype=jnp.int32), S)
             out = run_hop(level + 1, x1, valid1, gid1).reshape(gpr, S, d)
-        back = _unfold(out, spec, spec.capacity)
+        with jax.named_scope("exchange"):
+            back = _unfold(out, spec, spec.capacity)
         dropped = comm.psum((dec.valid & ~st.keep).sum().astype(jnp.float32),
                             sync)
         total = comm.psum(dec.valid.sum().astype(jnp.float32), sync)
         hop_drops[level] = dropped / jnp.maximum(total, 1)
-        return D.combine(back, st)
+        with jax.named_scope("combine"):
+            return D.combine(back, st)
 
     t = x.shape[0]
     if token_valid is None:

@@ -153,20 +153,32 @@ def _add_stats(a: MoEStats, b: MoEStats) -> MoEStats:
 def dense_block(p, x, cfg, plan, positions, cache, *, use_kernel=False,
                 token_valid=None):
     window = cfg.window if cfg.attention == "sliding" else 0
-    h, cache = _attn_fwd(p["attn"], L.apply_norm(p["ln1"], x, cfg.norm), cfg,
-                         plan, positions, cache, window, use_kernel)
-    x = x + h
-    h = L.ffn_forward(p["ffn"], L.apply_norm(p["ln2"], x, cfg.norm), cfg, plan)
-    x = x + h
+    with jax.named_scope("attention"):
+        h, cache = _attn_fwd(p["attn"], L.apply_norm(p["ln1"], x, cfg.norm),
+                             cfg, plan, positions, cache, window, use_kernel)
+        x = x + h
+    with jax.named_scope("ffn"):
+        h = L.ffn_forward(p["ffn"], L.apply_norm(p["ln2"], x, cfg.norm), cfg,
+                          plan)
+        x = x + h
     return x, _zero_stats(), cache
 
 
 def moe_block(p, x, cfg, plan, positions, cache, *, use_kernel=False,
               token_valid=None):
     window = cfg.window if cfg.attention == "sliding" else 0
-    h, cache = _attn_fwd(p["attn"], L.apply_norm(p["ln1"], x, cfg.norm), cfg,
-                         plan, positions, cache, window, use_kernel)
-    x = x + h
+    with jax.named_scope("attention"):
+        h, cache = _attn_fwd(p["attn"], L.apply_norm(p["ln1"], x, cfg.norm),
+                             cfg, plan, positions, cache, window, use_kernel)
+        x = x + h
+    with jax.named_scope("moe"):
+        x, stats = _moe_ffn(p, x, cfg, plan, use_kernel, token_valid)
+    return x, stats, cache
+
+
+def _moe_ffn(p, x, cfg, plan, use_kernel, token_valid):
+    """The MoE sublayer of :func:`moe_block`: norm, token split, routed and
+    shared experts, unsplit, residual."""
     hn = L.apply_norm(p["ln2"], x, cfg.norm)
     B, T, d = hn.shape
     flat = hn.reshape(B * T, d)
@@ -192,8 +204,7 @@ def moe_block(p, x, cfg, plan, positions, cache, *, use_kernel=False,
         y_loc = y_loc + hh @ ps["w2"].astype(loc.dtype)
     y = comm.name_saved(
         comm.unsplit_tokens(y_loc, plan.tp_axis, B * T)).reshape(B, T, d)
-    x = x + y
-    return x, stats, cache
+    return x + y, stats
 
 
 def rwkv_block(p, x, cfg, plan, positions, cache, *, use_kernel=False,
@@ -400,7 +411,8 @@ def forward(params: Dict, tokens: jax.Array, cfg0: ModelConfig,
     negative ``positions`` (see ``serve/engine.py``)."""
     cfg = _model_cfg(cfg0, plan)
     stages = build_stages(cfg)
-    x = embed_inputs(params, tokens, cfg, plan, extra)
+    with jax.named_scope("embed"):
+        x = embed_inputs(params, tokens, cfg, plan, extra)
     acc = _zero_stats()
     new_caches = []
     for i, st in enumerate(stages):
@@ -411,7 +423,8 @@ def forward(params: Dict, tokens: jax.Array, cfg0: ModelConfig,
                                     token_valid=token_valid)
         acc = _add_stats(acc, stats)
         new_caches.append(c)
-    logits = model_logits(params, x, cfg, plan)
+    with jax.named_scope("lm_head"):
+        logits = model_logits(params, x, cfg, plan)
     return x, logits, acc, (None if caches is None else tuple(new_caches))
 
 

@@ -44,18 +44,20 @@ def _ce_loss(params, batch, cfg: ModelConfig, plan: MeshPlan,
     h, logits, stats, _ = T.forward(params, tokens, cfg, plan,
                                     positions=positions, extra=extra or None,
                                     remat=cfg.remat, use_kernel=use_kernel)
-    if cfg.num_codebooks > 1:
-        labels_t = jnp.swapaxes(labels, 1, 2)            # (B,S,K)
-        ce = vocab_parallel_xent(logits, labels_t, plan)
-        mask = labels_t != IGNORE
-    else:
-        ce = vocab_parallel_xent(logits, labels, plan)
-        mask = labels != IGNORE
-    loss_sum = jnp.sum(ce * mask)
-    cnt = jnp.sum(mask).astype(jnp.float32)
-    # tokens are distinct across dp axes only (replicated over tp)
-    cnt_global = comm.psum(cnt, plan.dp_axes)
-    ce_mean = comm.psum(loss_sum, plan.dp_axes) / jnp.maximum(cnt_global, 1.0)
+    with jax.named_scope("lm_head"):
+        if cfg.num_codebooks > 1:
+            labels_t = jnp.swapaxes(labels, 1, 2)        # (B,S,K)
+            ce = vocab_parallel_xent(logits, labels_t, plan)
+            mask = labels_t != IGNORE
+        else:
+            ce = vocab_parallel_xent(logits, labels, plan)
+            mask = labels != IGNORE
+        loss_sum = jnp.sum(ce * mask)
+        cnt = jnp.sum(mask).astype(jnp.float32)
+        # tokens are distinct across dp axes only (replicated over tp)
+        cnt_global = comm.psum(cnt, plan.dp_axes)
+        ce_mean = (comm.psum(loss_sum, plan.dp_axes)
+                   / jnp.maximum(cnt_global, 1.0))
     # --- partition loss for the gradient path --------------------------------
     # Under shard_map autodiff (check_vma=False) the backward pass effectively
     # differentiates the SUM of every device's loss output. A replicated loss
@@ -138,21 +140,24 @@ def train_step_fn(params, opt_state, batch, step, sent=None, *,
         # ZeRO-1: reduce-scatter raw grads + global clip scale first; the
         # apply (moments + owned-chunk update + re-gather) is a separate
         # stage so the sentinel can gate it (see optim/zero1.py)
-        g_upd, gnorm, scale = zero1_reduce_and_clip(
-            grads, sync_axes_tree=sync_axes_tree,
-            norm_axes_tree=norm_axes_tree, plan=plan,
-            grad_clip=tcfg.grad_clip)
+        with jax.named_scope("grad_sync"):
+            g_upd, gnorm, scale = zero1_reduce_and_clip(
+                grads, sync_axes_tree=sync_axes_tree,
+                norm_axes_tree=norm_axes_tree, plan=plan,
+                grad_clip=tcfg.grad_clip)
         apply_fn = lambda g, o, p: zero1_apply(
             g, scale, o, p, lr, sync_axes_tree=sync_axes_tree,
             norm_axes_tree=norm_axes_tree, plan=plan, b1=tcfg.b1,
             b2=tcfg.b2, eps=tcfg.eps, weight_decay=tcfg.weight_decay)
     else:
         # ---- explicit gradient reduction over replicated axes ---------------
-        grads = jax.tree.map(
-            lambda g, a: comm.psum(g, a) if a else g, grads, sync_axes_tree,
-            is_leaf=lambda x: isinstance(x, jax.Array))
-        g_upd, gnorm = clip_by_global_norm(grads, tcfg.grad_clip,
-                                           norm_axes_tree)
+        with jax.named_scope("grad_sync"):
+            grads = jax.tree.map(
+                lambda g, a: comm.psum(g, a) if a else g, grads,
+                sync_axes_tree, is_leaf=lambda x: isinstance(x, jax.Array))
+        with jax.named_scope("optimizer"):
+            g_upd, gnorm = clip_by_global_norm(grads, tcfg.grad_clip,
+                                               norm_axes_tree)
         apply_fn = lambda g, o, p: opt.update(g, o, p, lr,
                                               shard_axes=norm_axes_tree)
     if sentinel:
@@ -163,8 +168,9 @@ def train_step_fn(params, opt_state, batch, step, sent=None, *,
         # bad step
         ok, nonfin, spike = SEN.step_verdict(metrics["loss"], g_upd,
                                              sent, plan.all_axes)
-        params, opt_state = SEN.gated_update(ok, apply_fn, g_upd,
-                                             opt_state, params)
+        with jax.named_scope("optimizer"):
+            params, opt_state = SEN.gated_update(ok, apply_fn, g_upd,
+                                                 opt_state, params)
         alarm = SEN.router_alarm(metrics["max_load"],
                                  metrics["load_entropy"])
         sent = SEN.update_sentinel(sent, metrics["loss"], ok, nonfin,
@@ -172,7 +178,8 @@ def train_step_fn(params, opt_state, batch, step, sent=None, *,
         metrics = dict(metrics)
         metrics["skip"] = (~ok).astype(jnp.float32)
     else:
-        params, opt_state = apply_fn(g_upd, opt_state, params)
+        with jax.named_scope("optimizer"):
+            params, opt_state = apply_fn(g_upd, opt_state, params)
     metrics = dict(metrics)
     metrics["grad_norm"] = gnorm
     metrics["lr"] = lr
@@ -208,6 +215,9 @@ def build_train_step(cfg: ModelConfig, tcfg: TrainConfig, plan: MeshPlan,
                  schedule=schedule, sync_axes_tree=sync_tree,
                  norm_axes_tree=norm_tree, n_micro=n_micro,
                  use_kernel=use_kernel, zero1=zero1, sentinel=sentinel)
+    # jit names the module after the function: ``jit_train_step_fn`` on one
+    # device as on a mesh, where a bare partial gives ``jit__unknown``
+    fn.__name__ = train_step_fn.__name__
     if mesh is None:
         return jax.jit(fn, donate_argnums=(0, 1)), pspec
 

@@ -60,3 +60,8 @@ def test_zero1_equivalence():
 def test_fault_containment():
     out = _run("_faults.py", timeout=1800)
     assert "ALL FAULT CONTAINMENT OK" in out
+
+
+def test_collectives_sit_under_their_scopes():
+    out = _run("_scopes.py")
+    assert "SCOPES OK" in out

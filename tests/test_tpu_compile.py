@@ -9,16 +9,22 @@ experts, top-1, capacity 2.0, 4096 tokens per step.  The combine gather,
 whose VMEM grows with top-k and d_model, is also compiled at the top-8
 widths of qwen3-moe-30b-a3b (d_model 2048) and deepseek-v3-671b (7168).
 
+The reduced SMILE train step is compiled for the 2x2 host too, to pin the
+named scopes the benchmark splits device time by: every scope must reach
+the optimized program, and LAMB's update must lie under ``optimizer``.
+
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and under several test workers only the worker given this file may try.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from bench import scopes as S
 from repro.kernels.flash_attn import flash_attention_pallas
 from repro.kernels.grouped_ffn import (grouped_ffn_pallas,
                                        grouped_ffn_ragged_pallas)
@@ -113,3 +119,97 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
             for s, dt in shapes]
     compiled = jax.jit(kernel).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# =============================================================================
+# The train step's named scopes, as the compiled program keeps them
+# =============================================================================
+
+# ``  ROOT %fusion.52 = f32[...] fusion(...), ..., metadata={op_name="..."``
+_INSTR = re.compile(r'^\s*(?:ROOT\s+)?%([^\s=]+) = (.*)$')
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+
+
+@pytest.fixture(scope="module")
+def smile_step(topo, no_persistent_cache):
+    """The reduced SMILE train step (LAMB) compiled for the described 2x2
+    host: each instruction's text, by name, and the entry's output
+    operands in order (parameters, then LAMB's m, v and step, then the
+    metrics)."""
+    import numpy as np
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+    from repro.common.config import TrainConfig
+    from repro.configs import get_reduced
+    from repro.models.transformer import init_model
+    from repro.optim import make_optimizer, make_schedule
+    from repro.sharding.plan import plan_from_mesh
+    from repro.sharding.specs import batch_specs
+    from repro.train.step import build_train_step, opt_state_specs
+
+    mesh = jax.sharding.Mesh(np.array(topo.devices).reshape(2, 2),
+                             ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    plan = plan_from_mesh(mesh)
+    cfg = get_reduced("smile-3.7b")
+    tcfg = TrainConfig(global_batch_size=8, seq_len=32, optimizer="lamb",
+                       lr=1e-3, warmup_steps=2, grad_clip=1.0)
+    opt = make_optimizer("lamb")
+    params = jax.eval_shape(lambda: init_model(jax.random.PRNGKey(0), cfg,
+                                               plan))
+    batch = {k: jax.ShapeDtypeStruct((8, 32), I32) for k in ("tokens", "labels")}
+    step, pspec = build_train_step(cfg, tcfg, plan, opt,
+                                   make_schedule("cosine", 1e-3, 2, 100),
+                                   params, batch, mesh=mesh)
+
+    def like(tree, specs):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                              sharding=NamedSharding(mesh, s)),
+            tree, specs, is_leaf=lambda x: isinstance(x, P))
+
+    args = (like(params, pspec),
+            like(jax.eval_shape(opt.init, params), opt_state_specs(pspec, plan)),
+            like(batch, batch_specs(batch, plan)),
+            like(jax.ShapeDtypeStruct((), I32), P()))
+    text = step.lower(*args).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    instrs = {}
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            instrs[m.group(1)] = m.group(2)
+    root = re.search(r"ROOT %\S+ = .*? tuple\(([^)]*)\)", entry).group(1)
+    outputs = [re.sub(r"/\*.*?\*/", "", o).strip().lstrip("%")
+               for o in root.split(",")]
+    n_state = 3 * len(jax.tree.leaves(params)) + 1
+    return instrs, outputs, n_state
+
+
+def test_train_step_keeps_every_scope(smile_step):
+    instrs, _, _ = smile_step
+    seen = set()
+    for body in instrs.values():
+        m = _OP_NAME.search(body)
+        if m:
+            seen.update(S.scope_path(m.group(1)))
+    want = S.LAYERS + S.PHASES + ("hop0", "hop1")
+    missing = [s for s in want if s not in seen]
+    assert not missing, f"scopes missing from the compiled step: {missing}"
+
+
+def test_lamb_update_lies_under_optimizer(smile_step):
+    """Each new parameter and LAMB state leaf the step returns is made by
+    an op under ``optimizer`` (through copies, bitcasts and tuple
+    elements, which carry no scope of their own)."""
+    instrs, outputs, n_state = smile_step
+    assert len(outputs) > n_state
+    moves = ("copy", "copy-start", "copy-done", "bitcast", "get-tuple-element")
+    for name in outputs[:n_state]:
+        body = instrs[name]
+        # the opcode is the first word after a space to open an operand list
+        while re.search(r" ([a-z][a-z0-9-]*)\(", body).group(1) in moves:
+            name = re.search(r"\(%([^\s,)]+)", body).group(1)
+            body = instrs[name]
+        m = _OP_NAME.search(body)
+        assert m and S.scope_of(m.group(1))[0] == "optimizer", (name, body[:300])
