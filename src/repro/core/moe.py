@@ -174,11 +174,23 @@ def _grid(cfg: MoEConfig, plan: MeshPlan) -> Tuple[int, int]:
 
 def _my_expert_weights(w: Dict[str, jax.Array], layout: ExpertLayout,
                        plan: MeshPlan, b_n: int, b_m: int):
-    """Select this device's expert weights as (b_n * owned, d, f) groups.
+    """Select this device's expert weights (``wsel``): b_n * owned groups.
 
     Weights are stored (n_g, E_pn, d, f) sharded (inter, intra?) so the local
     leaf is (b_n, E_pn_local, d, f). For replicated layouts (r > 1) the leaf
     holds all per-node experts and we gather the ones backing our slots.
+
+    With the experts sharded intra-node and b_n > 1 the leaf is passed on
+    unmerged, (b_n, E_pn_local, d, f): merging b_n into the expert dimension
+    is a reshape XLA does not fuse across, so the weights' bf16 cast would
+    run stand-alone and the weight gradient would reach the optimizer in
+    the merged shape, where its clip scale cannot fuse into LAMB.  The padded
+    XLA FFN (:func:`~repro.core.pipeline.experts_ffn`) batches over both
+    group dimensions; every other consumer (the Pallas kernels, the ragged
+    and compact FFNs) merges it to (G, d, f) at its own entry
+    (:func:`~repro.core.pipeline.merge_groups`).  With b_n == 1 the merge
+    only drops a unit dimension and is kept; replicated layouts gather
+    (b_n * b_m, d, f).
     """
     out = {}
     if layout.shard_intra:
@@ -186,7 +198,7 @@ def _my_expert_weights(w: Dict[str, jax.Array], layout: ExpertLayout,
         for k, v in w.items():
             if v is None:
                 continue
-            out[k] = v.reshape((-1,) + v.shape[2:])
+            out[k] = v if b_n > 1 else v.reshape((-1,) + v.shape[2:])
         return out, b_n * w["w1"].shape[1]
     # replicated layout: slots j_lo..j_lo+b_m map to experts slot // r
     j = comm.axis_index(plan.ep_intra) * b_m

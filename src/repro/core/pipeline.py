@@ -189,18 +189,36 @@ def z_loss(logits: jax.Array, valid: jax.Array, coef: float, sync_axes):
 # via kernels.ops
 # =============================================================================
 
+def merge_groups(w: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """Expert leaves as (G, d, f) groups: a (b_n, E_local, d, f) leaf is
+    merged to G = b_n * E_local, node-major."""
+    return {k: v.reshape((-1,) + v.shape[-2:]) if v.ndim == 4 else v
+            for k, v in w.items()}
+
+
 def experts_ffn(w: Dict[str, jax.Array], x: jax.Array, act: str,
                 use_kernel: bool = False) -> jax.Array:
-    """Apply per-group expert FFN. ``x``: (G, T, d); weights (G, d, f)/(G, f, d)."""
+    """Apply per-group expert FFN. ``x``: (G, T, d); weights (G, d, f)/(G, f, d),
+    or (b_n, E_local, d, f)/(b_n, E_local, f, d) with G = b_n * E_local.
+
+    Four-dimensional leaves stay so: the tokens are viewed as
+    (b_n, E_local, T, d) and the einsums batch over both group dimensions,
+    so XLA fuses the weights' bf16 cast into the matmuls and the weight
+    gradient comes back in the parameter's own shape."""
     if use_kernel:
         from repro.kernels import ops as kops
+        w = merge_groups(w)
         return kops.grouped_ffn(x, w["w1"], w.get("w3"), w["w2"], act=act)
     actf = jax.nn.silu if act == "silu" else jax.nn.gelu
-    h = jnp.einsum("gtd,gdf->gtf", x, w["w1"].astype(x.dtype))
+    lead = w["w1"].shape[:-2]                     # (G,) or (b_n, E_local)
+    xg = x.reshape(lead + x.shape[1:])
+    b = "g" if len(lead) == 1 else "ng"           # einsum group dimensions
+    h = jnp.einsum(f"{b}td,{b}df->{b}tf", xg, w["w1"].astype(x.dtype))
     h = actf(h)
     if "w3" in w and w["w3"] is not None:
-        h = h * jnp.einsum("gtd,gdf->gtf", x, w["w3"].astype(x.dtype))
-    return jnp.einsum("gtf,gfd->gtd", h, w["w2"].astype(x.dtype))
+        h = h * jnp.einsum(f"{b}td,{b}df->{b}tf", xg, w["w3"].astype(x.dtype))
+    y = jnp.einsum(f"{b}tf,{b}fd->{b}td", h, w["w2"].astype(x.dtype))
+    return y.reshape(x.shape)
 
 
 def experts_ffn_ragged(w: Dict[str, jax.Array], rows: jax.Array,
@@ -215,6 +233,7 @@ def experts_ffn_ragged(w: Dict[str, jax.Array], rows: jax.Array,
     every tile belongs to exactly one group, so this is the jnp shadow of
     the Pallas kernel's scalar-prefetched weight indirection.
     """
+    w = merge_groups(w)
     if use_kernel:
         from repro.kernels import ops as kops
         return kops.grouped_ffn_ragged(rows, group_starts, w["w1"],
@@ -819,7 +838,9 @@ def execute_pipeline(x: jax.Array, hops: Sequence[ExpertHop],
     """Run a routing schedule expressed as a hop pipeline.
 
     ``x``: (t, d) local tokens; ``hops``: outermost-first; ``wsel``: this
-    device's expert weights, (gpr_innermost, d, f) groups in local order;
+    device's expert weights, (gpr_innermost, d, f) groups in local order,
+    or (b_n, E_local, d, f) with gpr_innermost = b_n * E_local (see
+    :func:`repro.core.moe._my_expert_weights`);
     ``cfg``: :class:`repro.common.config.MoEConfig` (dispatch backend, sort
     impl, z coefficient); ``sync``: mesh axes for globally-averaged stats.
 

@@ -12,6 +12,7 @@ from hypothesis import strategies as st
 
 from repro.common.config import MoEConfig
 from repro.core import moe as M
+from repro.core import pipeline as PL
 from repro.core.layout import make_layout
 from repro.sharding.plan import single_device_plan
 
@@ -126,6 +127,46 @@ def test_moe_layer_shapes_and_finiteness(router, grid, E, k, g, rng_key):
     assert y.shape == x.shape
     assert np.isfinite(np.asarray(y)).all()
     assert float(stats.drop_frac) < 0.5
+
+
+@pytest.mark.parametrize("router", ["switch", "smile"])
+@pytest.mark.parametrize("grid", [(2, 2), (4, 4)])
+def test_unmerged_expert_groups_match_merged(router, grid, rng_key,
+                                             monkeypatch):
+    """On one device the local expert leaf is (b_n, E_local, d, f) with
+    b_n = grid[0] > 1, and the padded FFN batches over both group
+    dimensions; outputs and expert-weight gradients equal those of the
+    same layer with the leaf merged to (b_n * E_local, d, f)."""
+    n, m = grid
+    cfg = MoEConfig(num_experts=2 * n * m, top_k=1, d_ff_expert=64,
+                    capacity_factor=2.0, router=router, grid=grid)
+    params = M.init_moe_params(rng_key, cfg, 32, PLAN, glu=True)
+    x = jax.random.normal(jax.random.PRNGKey(1), (96, 32))
+    cot = jax.random.normal(jax.random.PRNGKey(2), (96, 32))
+    seen = []
+    select = M._my_expert_weights
+
+    def run(merge):
+        def selected(*a):
+            wsel, n_groups = select(*a)
+            seen.append(wsel["w1"].ndim)
+            return (PL.merge_groups(wsel) if merge else wsel), n_groups
+
+        monkeypatch.setattr(M, "_my_expert_weights", selected)
+
+        def loss(experts):
+            y, _ = M.moe_layer(dict(params, experts=experts), x, cfg, PLAN,
+                               act="silu")
+            return jnp.sum(y * cot), y
+        return jax.grad(loss, has_aux=True)(params["experts"])
+
+    g4, y4 = run(merge=False)
+    g3, y3 = run(merge=True)
+    assert set(seen) == {4}                 # the unmerged leaf was passed on
+    np.testing.assert_allclose(y4, y3, rtol=1e-6, atol=1e-6)
+    for k in ("w1", "w2", "w3"):
+        np.testing.assert_allclose(g4[k], g3[k], rtol=1e-6, atol=1e-6,
+                                   err_msg=k)
 
 
 @pytest.mark.parametrize("router", ["switch", "smile"])

@@ -11,7 +11,9 @@ widths of qwen3-moe-30b-a3b (d_model 2048) and deepseek-v3-671b (7168).
 
 The reduced SMILE train step is compiled for the 2x2 host too, to pin the
 named scopes the benchmark splits device time by: every scope must reach
-the optimized program, and LAMB's update must lie under ``optimizer``.
+the optimized program, and LAMB's update must lie under ``optimizer``.  The
+one-chip SMILE and Switch steps are compiled at published widths to pin
+that the expert weights' bf16 casts and LAMB's clip scale stay fused.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
@@ -128,6 +130,37 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
 # ``  ROOT %fusion.52 = f32[...] fusion(...), ..., metadata={op_name="..."``
 _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%([^\s=]+) = (.*)$')
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_MOVES = ("copy", "copy-start", "copy-done", "bitcast", "get-tuple-element")
+
+
+def _parse(text):
+    """Each instruction's text after ``=``, by name, over every computation;
+    and the entry's output operands in order."""
+    entry = text[text.index("\nENTRY "):]
+    instrs = {}
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            instrs[m.group(1)] = m.group(2)
+    root = re.search(r"ROOT %\S+ = .*? tuple\(([^)]*)\)", entry).group(1)
+    outputs = [re.sub(r"/\*.*?\*/", "", o).strip().lstrip("%")
+               for o in root.split(",")]
+    return instrs, outputs
+
+
+def _opcode(body):
+    """The opcode: the first word after a space to open an operand list."""
+    return re.search(r" ([a-z][a-z0-9-]*)\(", body).group(1)
+
+
+def _producer(instrs, name):
+    """The instruction that makes ``name``'s value, through copies,
+    bitcasts and tuple elements (which carry no scope of their own)."""
+    body = instrs[name]
+    while _opcode(body) in _MOVES:
+        name = re.search(r"\(%([^\s,)]+)", body).group(1)
+        body = instrs[name]
+    return name
 
 
 @pytest.fixture(scope="module")
@@ -172,16 +205,7 @@ def smile_step(topo, no_persistent_cache):
             like(jax.eval_shape(opt.init, params), opt_state_specs(pspec, plan)),
             like(batch, batch_specs(batch, plan)),
             like(jax.ShapeDtypeStruct((), I32), P()))
-    text = step.lower(*args).compile().as_text()
-    entry = text[text.index("\nENTRY "):]
-    instrs = {}
-    for line in text.splitlines():
-        m = _INSTR.match(line)
-        if m:
-            instrs[m.group(1)] = m.group(2)
-    root = re.search(r"ROOT %\S+ = .*? tuple\(([^)]*)\)", entry).group(1)
-    outputs = [re.sub(r"/\*.*?\*/", "", o).strip().lstrip("%")
-               for o in root.split(",")]
+    instrs, outputs = _parse(step.lower(*args).compile().as_text())
     n_state = 3 * len(jax.tree.leaves(params)) + 1
     return instrs, outputs, n_state
 
@@ -204,12 +228,118 @@ def test_lamb_update_lies_under_optimizer(smile_step):
     elements, which carry no scope of their own)."""
     instrs, outputs, n_state = smile_step
     assert len(outputs) > n_state
-    moves = ("copy", "copy-start", "copy-done", "bitcast", "get-tuple-element")
     for name in outputs[:n_state]:
+        name = _producer(instrs, name)
         body = instrs[name]
-        # the opcode is the first word after a space to open an operand list
-        while re.search(r" ([a-z][a-z0-9-]*)\(", body).group(1) in moves:
-            name = re.search(r"\(%([^\s,)]+)", body).group(1)
-            body = instrs[name]
         m = _OP_NAME.search(body)
         assert m and S.scope_of(m.group(1))[0] == "optimizer", (name, body[:300])
+
+
+# =============================================================================
+# One chip: the expert weights' LAMB update and bf16 casts stay fused
+# =============================================================================
+
+_SHAPE = re.compile(r"\b(f32|bf16)\[([\d,]+)\]")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+
+
+def _computation_of(text):
+    """``{instruction name: name of the computation that holds it}``."""
+    out, comp = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _INSTR.match(line)
+        if m:
+            out[m.group(1)] = comp
+    return out
+
+
+@pytest.fixture(scope="module", params=["smile-3.7b", "switch-3.7b"])
+def one_chip_step(request, one_chip, no_persistent_cache):
+    """The benchmark's one-chip train step (2 layers, logical grid (2, 2),
+    LAMB) at published widths with a small batch, compiled for one
+    described v5e chip: instructions by name, the entry's outputs, the
+    number of state outputs, each instruction's computation, and the
+    element count of an expert weight leaf."""
+    import dataclasses
+    import math
+
+    from repro.common.config import TrainConfig
+    from repro.configs import get_config
+    from repro.models.transformer import init_model
+    from repro.optim import make_optimizer, make_schedule
+    from repro.sharding.plan import single_device_plan
+    from repro.train.step import build_train_step
+
+    cfg = get_config(request.param)
+    cfg = cfg.replace(num_layers=2, moe=dataclasses.replace(cfg.moe,
+                                                            grid=(2, 2)))
+    plan = single_device_plan()
+    tcfg = TrainConfig(global_batch_size=2, seq_len=128, optimizer="lamb",
+                       lr=3e-4, warmup_steps=1, grad_clip=1.0)
+    opt = make_optimizer("lamb")
+    params = jax.eval_shape(lambda: init_model(jax.random.PRNGKey(0), cfg,
+                                               plan))
+    batch = {k: jax.ShapeDtypeStruct((2, 128), I32) for k in ("tokens", "labels")}
+    step, _ = build_train_step(cfg, tcfg, plan, opt,
+                               make_schedule("cosine", 3e-4, 1, 100),
+                               params, batch)
+    like = lambda t: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), t)
+    args = (like(params), like(jax.eval_shape(opt.init, params)),
+            like(batch), jax.ShapeDtypeStruct((), I32, sharding=one_chip))
+    text = step.lower(*args).compile().as_text()
+    instrs, outputs = _parse(text)
+    experts = [x for path, x in jax.tree_util.tree_flatten_with_path(params)[0]
+               if "experts" in jax.tree_util.keystr(path)]
+    assert experts and experts[0].shape[1:3] == (2, E // 2), experts[0].shape
+    return (instrs, outputs, 3 * len(jax.tree.leaves(params)) + 1,
+            _computation_of(text), math.prod(experts[0].shape))
+
+
+def test_one_chip_expert_weights_stay_fused(one_chip_step):
+    """With two inter-node groups on the chip, the expert leaves keep their
+    (b_n, E_local) group dimensions through the expert FFN.  Then LAMB
+    reads the bf16 weight gradient with the clip scale fused in: no fusion
+    under ``optimizer`` writes an f32 expert-sized tensor other than LAMB's
+    m, v and new parameters.  And the matmuls cast the f32 weights
+    themselves: no expert weight is cast to bf16 on its own."""
+    import math
+
+    instrs, outputs, n_state, comp_of, n_expert = one_chip_step
+    fused = {c for b in instrs.values() if _opcode(b) == "fusion"
+             for c in re.findall(r"calls=%([^\s,]+)", b)}
+    top = {n: b for n, b in instrs.items() if comp_of[n] not in fused}
+
+    def expert_sized(body, dtype):
+        head = body[:body.index(" " + _opcode(body) + "(")]
+        return any(dt == dtype and math.prod(map(int, dims.split(",")))
+                   == n_expert for dt, dims in _SHAPE.findall(head))
+
+    def scope(body):
+        m = _OP_NAME.search(body)
+        return S.scope_of(m.group(1) if m else None)
+
+    state = {_producer(instrs, o) for o in outputs[:n_state]}
+    writes = [n for n, b in top.items() if _opcode(b) == "fusion"
+              and expert_sized(b, "f32") and (scope(b) or ("",))[0] == "optimizer"]
+    assert len(set(writes) & state) >= 4, writes   # LAMB's passes, w1 and w2
+    spills = sorted(set(writes) - state)
+    assert not spills, [(n, top[n][:200]) for n in spills]
+
+    def cast_only(body):
+        if _opcode(body) == "convert":
+            return True
+        if _opcode(body) != "fusion":
+            return False
+        called = re.search(r"calls=%([^\s,]+)", body).group(1)
+        ops = {_opcode(b) for n, b in instrs.items() if comp_of[n] == called}
+        return "convert" in ops and ops <= {"parameter", "convert", "bitcast",
+                                            "copy"}
+
+    casts = [n for n, b in top.items() if expert_sized(b, "bf16")
+             and cast_only(b)]
+    assert not casts, [(n, top[n][:200]) for n in casts]
