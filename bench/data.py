@@ -1,10 +1,16 @@
-"""Synthetic masked-LM traffic and the loader thread that feeds the window.
+"""Synthetic training traffic and the loader thread that feeds the window.
 
 The generator is a copy of the repository's C4-like stream (Zipf(1.3)
-unigrams, repeated 8-grams, BERT masking 15% with 80/10/10), kept here so
-that the yardstick cannot move with the program.  Parameters come from a
-traffic file under ``bench/traffic/``.  Batch ``i`` of a run is a function
-of ``(seed, i)`` alone, so the reference can draw the same rows again.
+unigrams, repeated 8-grams), kept here so that the yardstick cannot move
+with the program.  Parameters come from a traffic file under
+``bench/traffic/``, whose ``kind`` picks the labels:
+
+* ``mlm``: BERT masking, 15% of positions with 80/10/10;
+* ``clm``: next-token prediction over a stream of documents (see
+  :func:`clm_batch`).
+
+Batch ``i`` of a run is a function of ``(seed, i)`` alone, so the reference
+can draw the same rows again.
 """
 from __future__ import annotations
 
@@ -13,6 +19,7 @@ import threading
 
 import numpy as np
 
+EOS_ID = 2
 MASK_ID = 4
 IGNORE = -1
 SPECIAL_IDS = 8                 # ids below this are reserved for specials
@@ -52,14 +59,46 @@ def mlm_mask(rng: np.random.Generator, tokens: np.ndarray, vocab: int,
     return corrupted.astype(np.int32), labels
 
 
-def make_batch(traffic: dict, batch: int, vocab: int, seed: int,
-               index: int) -> dict:
-    """Batch ``index`` of the stream: ``tokens`` and ``labels``, (batch, seq)."""
-    rng = _rng(seed, index)
+def mlm_batch(rng: np.random.Generator, traffic: dict, batch: int,
+              vocab: int) -> dict:
     toks = synthetic_tokens(rng, batch, traffic["seq"], vocab,
                             zipf_a=traffic["zipf_a"], ngram=traffic["ngram"])
     tokens, labels = mlm_mask(rng, toks, vocab, traffic["mask_prob"])
     return {"tokens": tokens, "labels": labels}
+
+
+def clm_batch(rng: np.random.Generator, traffic: dict, batch: int,
+              vocab: int) -> dict:
+    """Next-token rows cut from one stream of documents.  Each document has
+    a lognormal length (``doc_len_median``, ``doc_len_sigma``) of Zipf
+    tokens with repeated n-grams, then ``EOS_ID``; the last one is cut where
+    the batch ends.  The stream is cut into ``batch`` rows of ``seq + 1``:
+    ``tokens`` is a row less its last id, ``labels`` the row less its first,
+    every position a target.  Attention is not masked at document
+    boundaries (the program has no segment mask), so a row's later
+    documents see its earlier ones."""
+    seq = traffic["seq"]
+    need = batch * (seq + 1)
+    mu = np.log(traffic["doc_len_median"])
+    docs, have = [], 0
+    while have < need:
+        n = max(int(round(np.exp(mu + traffic["doc_len_sigma"]
+                                 * rng.standard_normal()))), 1)
+        doc = synthetic_tokens(rng, 1, n, vocab, zipf_a=traffic["zipf_a"],
+                               ngram=traffic["ngram"])[0]
+        docs.append(np.append(doc, np.int32(EOS_ID))[:need - have])
+        have += len(docs[-1])
+    rows = np.concatenate(docs).reshape(batch, seq + 1)
+    return {"tokens": rows[:, :-1].copy(), "labels": rows[:, 1:].copy()}
+
+
+KINDS = {"mlm": mlm_batch, "clm": clm_batch}
+
+
+def make_batch(traffic: dict, batch: int, vocab: int, seed: int,
+               index: int) -> dict:
+    """Batch ``index`` of the stream: ``tokens`` and ``labels``, (batch, seq)."""
+    return KINDS[traffic["kind"]](_rng(seed, index), traffic, batch, vocab)
 
 
 class Loader:

@@ -121,16 +121,33 @@ def peak(device_kind: str) -> dict:
 
 
 def reference_module(cfg: dict):
+    """The configuration's plain reference, ``bench/references/<name>.py``
+    (what it exports: ``bench/references/__init__.py``)."""
     return importlib.import_module(f"bench.references.{cfg['reference']}")
 
 
-def enable_compile_cache() -> str:
+def train_flops_per_token(cfg: dict, seq: int) -> int:
+    """Model FLOPs of a training step per token: the reference's count where
+    it gives one, else the MLM encoder's (``bench/flops.py``)."""
+    count = getattr(reference_module(cfg), "train_flops_per_token",
+                    F.train_flops_per_token)
+    return count(cfg, seq)
+
+
+def enable_compile_cache(metadata: bool = False) -> str:
     """JAX's persistent cache at a fixed path in the checkout, unless
-    ``JAX_COMPILATION_CACHE_DIR`` names one."""
+    ``JAX_COMPILATION_CACHE_DIR`` names one.
+
+    JAX keys the cache on the program without its metadata, so a step
+    compiled before a scope was renamed would come back with the old
+    ``op_name``s, and every op of it unattributed.  ``metadata`` keys on
+    them too: a run that reads the named scopes sets it; a run that does not
+    leaves the key, and its set-up, as they were."""
     import jax
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(ROOT / ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", metadata)
     return path
 
 
@@ -506,6 +523,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
             win = prog.run(seconds=seconds, tracer=tracer)
             counter.active = False
             mem = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devices]
+            scope_map = _op_scopes(prog) if trace else None
             prog.close()
             del prog
     finally:
@@ -526,7 +544,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         tr = T.load(tdir)
         shutil.rmtree(tdir, ignore_errors=True)
         metrics, bd = per_layer_metrics(cell, tr, win, step_module,
-                                        metrics_mods, pk, device, log)
+                                        metrics_mods, pk, device, log,
+                                        op_scopes=scope_map)
     else:
         step_s = spans(win["done"], win["t0"], cell.workload["span_steps"])
         metrics = {"tokens_per_s": {"value": tokens_per_s, "unit": "tokens/s"},
@@ -546,23 +565,42 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     return out
 
 
+def _lower_step(prog: Program):
+    """The step lowered for the arguments the window gives it."""
+    import jax
+    sh = prog.batch_sh
+    b = {k: jax.ShapeDtypeStruct((prog.cell.batch, prog.cell.seq), np.int32,
+                                 sharding=sh[k] if isinstance(sh, dict) else sh)
+         for k in ("tokens", "labels")}
+    return prog.step_fn.lower(prog.params, prog.opt_state, b, np.int32(1))
+
+
 def _module_name(prog: Program) -> str:
     """Name of the step program's HLO module, as the trace shows it."""
-    import jax
-    b = {k: jax.ShapeDtypeStruct((prog.cell.batch, prog.cell.seq), np.int32)
-         for k in ("tokens", "labels")}
-    low = prog.step_fn.lower(prog.params, prog.opt_state, b, np.int32(1))
+    low = _lower_step(prog)
     return str(low.compiler_ir().operation.attributes["sym_name"]).strip('"')
 
 
+def _op_scopes(prog: Program) -> dict:
+    """``{instruction: op_name}`` of the step executable that ran, from its
+    HLO text (the compile is a hit in the persistent cache)."""
+    from bench import scopes
+    return scopes.op_scopes(_lower_step(prog).compile().as_text())
+
+
 class RunView:
-    """What a per-layer reader may read of a traced run."""
+    """What a per-layer reader may read of a traced run.  ``op_scopes`` maps
+    the step's instructions to their ``op_name`` (``bench/scopes.py``); None
+    where the run did not read it."""
+
+    op_scopes: Optional[dict] = None
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
 
 
-def per_layer_metrics(cell, tr, win, step_module, mods, pk, device, log):
+def per_layer_metrics(cell, tr, win, step_module, mods, pk, device, log,
+                      op_scopes=None):
     """The per-layer metrics of a traced run, read from its trace ``tr``
     once each device's window is cut to its whole steps.  Sets ``device``'s
     ``busy_s`` and ``window_s``; returns the metrics and the breakdown."""
@@ -577,8 +615,9 @@ def per_layer_metrics(cell, tr, win, step_module, mods, pk, device, log):
     rate = T.traced_steps_per_s(tr)
     view = RunView(trace=tr, step_module=step_module, chips=cell.chips, peak=pk,
                    traced_tokens_per_s=rate and rate * cell.tokens_per_step,
-                   flops_per_token=F.train_flops_per_token(cell.config, cell.seq),
-                   input_wait_ms=1e3 * win["wait"] / max(len(win["done"]), 1))
+                   flops_per_token=train_flops_per_token(cell.config, cell.seq),
+                   input_wait_ms=1e3 * win["wait"] / max(len(win["done"]), 1),
+                   op_scopes=op_scopes)
     metrics = {}
     for name, mod in mods.items():
         v = mod.read(view)

@@ -32,7 +32,7 @@ def main() -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args()
     from bench import harness
-    harness.enable_compile_cache()
+    harness.enable_compile_cache(metadata=bool(args.trace))
     try:
         out = harness.run_cell(args.workload, args.seed, args.seconds,
                                bool(args.trace), t_start=T_START)

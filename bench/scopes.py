@@ -194,7 +194,6 @@ def measure(name: str, seed: int, log) -> dict:
     import time
 
     import jax
-    import numpy as np
 
     from bench import harness as H
     cell = H.load_cell(name)
@@ -204,16 +203,13 @@ def measure(name: str, seed: int, log) -> dict:
     steps = cell.workload["trace_steps"]
     prog.run(steps=H.TRACE_AFTER + steps + 1, tracer=H.Tracer(tdir, steps))
     module = H._module_name(prog)
-    batch = prog.put(prog.loader.get())
     t = time.perf_counter()
-    text = prog.step_fn.lower(prog.params, prog.opt_state, batch,
-                              np.int32(1)).compile().as_text()
+    scopes = H._op_scopes(prog)
     compile_s = time.perf_counter() - t
     prog.close()
     tr = T.load(tdir)
     shutil.rmtree(tdir, ignore_errors=True)
     T.trim_to_steps(tr, module)
-    scopes = op_scopes(text)
     log(f"[scopes] {name}: module {module}, {len(scopes)} instructions with "
         f"an op_name, compile for op_scopes {compile_s:.3f} s")
     return {"workload": name, "seed": seed, "module": module,
@@ -231,13 +227,7 @@ def main() -> None:
     ap.add_argument("--workload", nargs="+", required=True)
     ap.add_argument("--seed", type=int, required=True)
     args = ap.parse_args()
-    H.enable_compile_cache()
-    # JAX keys its persistent cache on the program without its metadata, so
-    # a step compiled before a scope moved would be loaded with the old
-    # op_names; key on them too, so that the executable that runs is
-    # compiled from this source's scopes
-    import jax
-    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    H.enable_compile_cache(metadata=True)
     log = lambda s: print(s, file=sys.stderr, flush=True)
     lines = []
     for w in args.workload:

@@ -18,6 +18,14 @@ from bench.tests.tiny import tiny_cell
 SEED = 2**31 + 77            # larger than 32 signed bits hold
 
 
+def _float32_program(monkeypatch):
+    """The program with its activations in float32."""
+    import repro.models.transformer as TT
+    embed = TT.embed_inputs
+    monkeypatch.setattr(TT, "embed_inputs",
+                        lambda *a, **k: embed(*a, **dict(k, dtype=jnp.float32)))
+
+
 def _readings(cell, seed):
     prog = H.Program(cell, seed, jax.devices()[:1])
     try:
@@ -31,10 +39,7 @@ def test_reference_matches_program_in_float32(name, monkeypatch):
     """With its activations in float32 (the configuration runs them in
     bfloat16), the program's step is the reference's to float32 rounding:
     routing, capacity drops, losses, gradient clipping and LAMB included."""
-    import repro.models.transformer as TT
-    embed = TT.embed_inputs
-    monkeypatch.setattr(TT, "embed_inputs",
-                        lambda *a, **k: embed(*a, **dict(k, dtype=jnp.float32)))
+    _float32_program(monkeypatch)
     cell = tiny_cell(name)
     got = _readings(cell, SEED)
     ref = H.run_reference(cell, SEED, jax.devices()[0])
@@ -65,9 +70,10 @@ def test_fault_under_the_timed_path_is_not_correct(fault):
 
 
 def test_mesh_faults_are_not_correct():
-    """On four virtual devices: the exchange between chips left out, the
-    gradient sync left out, and half the batch left out, each make the
-    four-chip cell's run incorrect."""
+    """On four virtual devices, for the four-chip cell with SMILE's routing
+    and with Switch's: the program in float32 is the reference's step, and
+    the exchange between chips left out, the gradient sync left out, and
+    half the batch left out each make a run incorrect."""
     script = Path(__file__).with_name("mesh_faults.py")
     root = Path(H.__file__).resolve().parent.parent
     env = dict(os.environ, JAX_PLATFORMS="cpu",
@@ -77,3 +83,48 @@ def test_mesh_faults_are_not_correct():
                        text=True, timeout=900)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "MESH FAULTS CAUGHT" in r.stdout, r.stdout[-3000:]
+
+
+def test_a_next_token_cell_runs_from_new_files_alone(tmp_path, monkeypatch):
+    """A next-token (``clm``) cell is a new traffic file and a new cell file:
+    a whole run of it, past the look for a chip, comes back ``correct`` with
+    no benchmark file edited.  A plumbing check: the configuration is the
+    MLM encoder's, program and reference alike bidirectional."""
+    import json
+    import shutil
+    base = tmp_path / "bench"
+    shutil.copytree(H.BENCH, base, ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in base.rglob("*") if p.is_file()}
+    traffic = {"kind": "clm", "batch": 16, "seq": 512, "zipf_a": 1.3, "ngram": 8,
+               "doc_len_median": 256, "doc_len_sigma": 1.0}
+    (base / "traffic" / "clm16x512.json").write_text(json.dumps(traffic))
+    cell = dict(H.load_json("workloads", "smile-3.7b.mlm512"), traffic="clm16x512")
+    (base / "workloads" / "smile-3.7b.clm512.json").write_text(json.dumps(cell))
+    assert all(p.read_bytes() == b for p, b in before.items())
+    cell = tiny_cell("smile-3.7b.clm512", base)
+    assert cell.traffic["kind"] == "clm"
+    _float32_program(monkeypatch)
+    out = H.run_cell(cell.name, SEED, 0.5, False, t_start=time.perf_counter(),
+                     require_accelerator=False, cell=cell, log=lambda s: None)
+    assert out["correct"] is True, out["checks"]
+    assert out["checks"]["loss_gap"]["value"] < 1e-5, out["checks"]
+
+
+def test_scope_map_is_that_of_the_step_that_ran():
+    """The traced run's ``op_scopes`` come from the executable the window
+    drove (lowered for the batch's own sharding), and name every layer the
+    readers read."""
+    import numpy as np
+    from bench import scopes as S
+    cell = tiny_cell("switch-3.7b.mlm512")
+    prog = H.Program(cell, SEED, jax.devices()[:1])
+    try:
+        prog.check_steps()
+        got = H._op_scopes(prog)
+        ran = prog.step_fn.lower(prog.params, prog.opt_state,
+                                 prog.put(prog.loader.get()), np.int32(1))
+        assert got == S.op_scopes(ran.compile().as_text())
+    finally:
+        prog.close()
+    layers = {(S.scope_of(op) or (None,))[0] for op in got.values()}
+    assert {"optimizer", "moe", "attention", "embed", "lm_head"} <= layers

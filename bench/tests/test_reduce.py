@@ -1,5 +1,6 @@
 """The reduction from a profiler trace to per-layer metrics, on hand-built
 traces, and the model-FLOPs count against a count by hand."""
+import dataclasses
 import math
 
 import pytest
@@ -191,3 +192,36 @@ def test_per_step_time_spans():
     assert H.spans([1.0, 2.0, 3.5, 4.0], 0.0, 1) == [1.0, 1.0, 1.5, 0.5]
     assert H.spans([1.0, 2.0, 3.5, 4.0], 0.0, 2) == [1.0, 1.25, 1.0]
     assert math.isclose(H.p95([float(i) for i in range(101)]), 95.0)
+
+
+@pytest.mark.parametrize("name,want", [
+    ("smile-3.7b.mlm512", 242_721_792),
+    ("switch-3.7b.mlm512", 243_007_488),
+    ("smile-3.7b.mlm512.4chip", 242_721_792),
+])
+def test_flops_per_token_of_the_cells_as_pinned(name, want):
+    """The configurations' reference gives no count, so ``mfu`` reads the
+    MLM encoder's, as before the reference could give one."""
+    cell = H.load_cell(name)
+    assert H.train_flops_per_token(cell.config, cell.seq) == want
+
+
+def test_a_reference_that_counts_its_flops_is_used(monkeypatch):
+    import sys
+    import types
+    from bench.references import mlm_moe
+    ref = types.ModuleType("bench.references.counting")
+    ref.__dict__.update({k: v for k, v in vars(mlm_moe).items()
+                         if not k.startswith("__")})
+    ref.train_flops_per_token = lambda cfg, seq: 7 * seq
+    monkeypatch.setitem(sys.modules, "bench.references.counting", ref)
+    cell = H.load_cell("smile-3.7b.mlm512")
+    cfg = dict(cell.config, reference="counting")
+    assert H.train_flops_per_token(cfg, 512) == 3584
+    tr = _trace([_steps(6)], window=(-0.5, 31.0))
+    metrics, _ = H.per_layer_metrics(
+        dataclasses.replace(cell, config=cfg), tr, {"done": [], "wait": 0.0},
+        "jit_train_step_fn", H.metric_modules(), {"bf16_flops": 197e12}, {},
+        log=lambda s: None)
+    assert metrics["mfu"]["value"] == pytest.approx(
+        100.0 * 3584 * cell.tokens_per_step * 200.0 / 197e12)

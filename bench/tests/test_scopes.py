@@ -158,3 +158,59 @@ def test_existing_readers_read_what_they_read():
     assert mods["device_idle"].read(view) == pytest.approx(20.0)
     for dev in S.split_ms(tr, SCOPES):
         assert sum(dev["layers_ms"].values()) == pytest.approx(dev["step_module_ms"])
+
+
+# one step: each op's scope (as its HLO metadata gives it) and device time
+READER_OPS = [("fusion.1", "shard_map/optimizer/mul", 2.0),
+              ("fusion.2", "transpose(jvp())/while/body/checkpoint/attention/dot_general", 1.5),
+              ("all-to-all.3", "jvp()/while/body/moe/hop0/exchange/all_to_all", 1.0),
+              ("fusion.4", "jvp(embed)/jit(_take)/gather", 0.25),
+              ("fusion.5", "transpose(jvp(lm_head))/dot_general", 0.5),
+              ("copy.6", None, 0.25)]
+READER_HLO = "\n".join(
+    ["ENTRY %main.1 (p: f32[4]) -> f32[4] {"]
+    + [f"  %{name} = f32[4]{{0}} fusion(%p), kind=kLoop, "
+       f'metadata={{op_name="{JIT}/{op}" source_line=1}}'
+       for name, op, _ in READER_OPS if op is not None]
+    + ["  ROOT %copy.6 = f32[4]{0} copy(%p)", "}"])
+
+
+def _reader_trace(devices=2):
+    ops, mods = [], []
+    for i in range(5):
+        t = 7.0 * i
+        mods.append(("jit_train_step_fn(12)", t, 5.5))
+        for name, _, dur in READER_OPS:
+            ops.append((name, t, dur))
+            t += dur
+    tr = _trace([(ops, mods)] * devices, window=(-1.0, 36.0))
+    T.trim_to_steps(tr, "jit_train_step_fn")
+    return tr
+
+
+@pytest.mark.parametrize("reader,want", [
+    ("optimizer_ms", 2.0), ("attention_ms", 1.5), ("moe_ms", 1.0),
+    ("lm_head_ms", 0.75)])
+def test_scope_readers_from_trace_and_hlo_text(reader, want):
+    tr = _reader_trace()
+    assert [d.steps for d in tr.devices] == [3, 3]
+    mod = H.metric_modules()[reader]
+    view = H.RunView(trace=tr, op_scopes=S.op_scopes(READER_HLO))
+    assert mod.read(view) == pytest.approx(want)
+    assert mod.read(H.RunView(trace=tr)) is None
+
+
+def test_scope_readers_sum_within_the_step():
+    """The four readers add up to the step less the ops of no scope."""
+    tr = _reader_trace()
+    metrics, _ = H.per_layer_metrics(
+        H.load_cell("smile-3.7b.mlm512"), tr, {"done": [], "wait": 0.0},
+        "jit_train_step_fn", H.metric_modules(), None, {}, log=lambda s: None,
+        op_scopes=S.op_scopes(READER_HLO))
+    four = sum(metrics[k]["value"] for k in
+               ("optimizer_ms", "attention_ms", "moe_ms", "lm_head_ms"))
+    assert four == pytest.approx(metrics["step_device_ms"]["value"] - 0.25)
+    metrics, _ = H.per_layer_metrics(
+        H.load_cell("smile-3.7b.mlm512"), tr, {"done": [], "wait": 0.0},
+        "jit_train_step_fn", H.metric_modules(), None, {}, log=lambda s: None)
+    assert not {"optimizer_ms", "attention_ms", "moe_ms", "lm_head_ms"} & set(metrics)

@@ -5,8 +5,8 @@ import copy
 from bench import harness as H
 
 
-def tiny_cell(name: str) -> H.Cell:
-    c = H.load_cell(name)
+def tiny_cell(name: str, base=H.BENCH) -> H.Cell:
+    c = H.load_cell(name, base)
     cfg = copy.deepcopy(c.config)
     cfg["model"].update(d_model=64, num_heads=4, num_kv_heads=4, d_ff=128,
                         vocab_size=512)
